@@ -1,44 +1,2 @@
 """repro: Dory-JAX — persistent homology at scale + multi-pod LM framework."""
 __version__ = "1.0.0"
-
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental with the old
-    # ``check_rep`` kwarg; call sites in this repo use the stable
-    # ``jax.shard_map(..., check_vma=...)`` API, so bridge it here.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=None,
-                          check_rep=None, **kwargs):
-        if check_rep is None:
-            check_rep = True if check_vma is None else check_vma
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-# Old jax (< ~0.5) Compiled.cost_analysis() returned a one-element list per
-# executable; newer jax returns the dict directly and call sites in this
-# repo (launch/dryrun and the test contract, which calls the method on the
-# Compiled object itself) index it as a dict.  The unwrap is idempotent —
-# on a dict-returning jax it never fires — and guarded so a jax refactor
-# that moves the class degrades to a no-op instead of an import error.
-try:
-    from jax._src import stages as _stages
-
-    if not getattr(_stages.Compiled.cost_analysis, "_repro_compat", False):
-        _orig_cost_analysis = _stages.Compiled.cost_analysis
-
-        def _cost_analysis_compat(self):
-            out = _orig_cost_analysis(self)
-            if isinstance(out, list) and len(out) == 1:
-                return out[0]
-            return out
-
-        _cost_analysis_compat._repro_compat = True
-        _stages.Compiled.cost_analysis = _cost_analysis_compat
-except (ImportError, AttributeError):
-    pass
-
-del _jax

@@ -3,18 +3,17 @@
 Dory's output is only as good as a set of fragile invariants: exact GF(2)
 algebra (``R = ∂V``, unique pivot lows), canonical filtration tie-breaking,
 and lock-step collective schedules across mesh shards.  The repo's own
-history shows these break *silently* — the PR 2 interpret-mode Ref-mutation
-discharge bug, the f32-candidate/f64-refine dtype discipline of the tiled
-harvest, the ``exchange_every`` cadence rules of the distributed reduction.
+history shows these break *silently* — the f32-candidate/f64-refine dtype
+discipline of the tiled harvest, the ``exchange_every`` cadence rules of the
+distributed reduction.
 This package is the gate that catches that bug class before (or the moment)
 it ships, in three layers:
 
 * :mod:`repro.analyze.lint` — an AST lint pass with repo-specific rules
-  derived from bugs we have actually shipped (Pallas ``Ref`` stores inside
-  traced loop bodies, host↔device syncs in superstep/harvest hot loops,
-  raw sorts on filtration values without the canonical ``(length, i, j)``
-  tie-break, f32 candidates compared against exact thresholds, unseeded
-  RNG in benchmarks).  Deliberate exceptions carry a justified
+  derived from bugs we have actually shipped (host↔device syncs in
+  superstep/harvest hot loops, raw sorts on filtration values without the
+  canonical ``(length, i, j)`` tie-break, f32 candidates compared against
+  exact thresholds, unseeded RNG in benchmarks).  Deliberate exceptions carry a justified
   ``# analyze: allow[rule] why`` pragma — a bare pragma is itself a
   finding.
 * :mod:`repro.analyze.collectives` — a jaxpr/HLO walker that extracts the

@@ -3,11 +3,6 @@
 Every rule here encodes a bug class this repo has actually hit (or is one
 code review away from hitting):
 
-* ``pallas-ref-mutation`` — a Pallas kernel may mutate a ``Ref`` only via
-  top-level ``ref[...] = value`` stores.  Stores issued from inside a
-  nested ``def``/``lambda`` (a ``fori_loop``/``scan``/``cond`` body) are
-  traced into a *different* scope and are silently dropped when the
-  kernel is discharged in interpret mode — the PR 2 discharge bug class.
 * ``host-sync`` — ``.item()``, ``np.asarray(device_fn(...))``,
   ``jax.device_get`` and ``block_until_ready`` inside a superstep or
   harvest hot loop serialize the pipeline on a device round-trip per
@@ -66,7 +61,6 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 __all__ = [
     "Finding",
     "Rule",
-    "RefMutationRule",
     "HostSyncRule",
     "RawFiltrationSortRule",
     "DtypeBoundaryRule",
@@ -129,68 +123,6 @@ class Rule:
     def _finding(self, relpath: str, node: ast.AST, message: str) -> Finding:
         return Finding(relpath, getattr(node, "lineno", 0),
                        getattr(node, "col_offset", 0), self.name, message)
-
-
-class RefMutationRule(Rule):
-    """Pallas ``Ref`` stores are only legal at kernel top level.
-
-    A function is treated as a kernel when it has parameters named
-    ``*_ref`` / ``*_refs`` (the repo-wide Pallas naming convention).
-    Inside it, any ``ref[...] = ...`` (or ``ref[...] ^= ...``) issued
-    from a nested ``def`` or ``lambda`` — i.e. a ``fori_loop`` / ``scan``
-    / ``cond`` body that Pallas traces as a separate scope — is flagged:
-    interpret-mode discharge drops those stores silently.
-    """
-
-    name = "pallas-ref-mutation"
-
-    def check(self, tree: ast.Module, source: str,
-              relpath: str) -> List[Finding]:
-        findings: List[Finding] = []
-        for fn in ast.walk(tree):
-            if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                continue
-            args = fn.args
-            params = [a.arg for a in (args.posonlyargs + args.args
-                                      + args.kwonlyargs)]
-            refs = {p for p in params
-                    if p.endswith("_ref") or p.endswith("_refs")}
-            if not refs:
-                continue
-            findings.extend(self._check_kernel(fn, refs, relpath))
-        return findings
-
-    def _check_kernel(self, kernel: ast.AST, refs: Set[str],
-                      relpath: str) -> List[Finding]:
-        findings: List[Finding] = []
-
-        def is_ref_store(target: ast.AST) -> bool:
-            return (isinstance(target, ast.Subscript)
-                    and isinstance(target.value, ast.Name)
-                    and target.value.id in refs)
-
-        def visit(node: ast.AST, nested: bool) -> None:
-            for child in ast.iter_child_nodes(node):
-                child_nested = nested or isinstance(
-                    child, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda))
-                if nested and isinstance(child, ast.Assign) and any(
-                        is_ref_store(t) for t in child.targets):
-                    findings.append(self._finding(
-                        relpath, child,
-                        "Ref store inside a nested trace scope (fori_loop/"
-                        "scan/cond body); interpret-mode discharge drops it "
-                        "— hoist the store to kernel top level or carry the "
-                        "value through the loop carry"))
-                elif nested and isinstance(child, ast.AugAssign) and \
-                        is_ref_store(child.target):
-                    findings.append(self._finding(
-                        relpath, child,
-                        "in-place Ref update inside a nested trace scope; "
-                        "interpret-mode discharge drops it"))
-                visit(child, child_nested)
-
-        visit(kernel, nested=False)
-        return findings
 
 
 class HostSyncRule(Rule):
@@ -661,9 +593,9 @@ class RetryWithoutBackoffRule(Rule):
 
 
 def default_rules() -> List[Rule]:
-    return [RefMutationRule(), HostSyncRule(), RawFiltrationSortRule(),
-            DtypeBoundaryRule(), UnseededRngRule(), RawTimingRule(),
-            SpanLeakRule(), BareExceptRule(), RetryWithoutBackoffRule()]
+    return [HostSyncRule(), RawFiltrationSortRule(), DtypeBoundaryRule(),
+            UnseededRngRule(), RawTimingRule(), SpanLeakRule(),
+            BareExceptRule(), RetryWithoutBackoffRule()]
 
 
 _ALLOW = re.compile(

@@ -249,6 +249,8 @@ def compute_ph(
                     filt, tile_stats = build_filtration_tiled(
                         points=points, dists=dists, tau_max=tau_max,
                         tile_m=tile_m, tile_n=tile_n, return_stats=True)
+                reg.gauge("harvest_pallas").set(
+                    float(tile_stats.backend == "pallas"))
             elif backend == "dense":
                 filt = build_filtration(points=points, dists=dists,
                                         tau_max=tau_max)

@@ -189,6 +189,7 @@ class _PackedBatch:
         self.scalar: Dict[int, np.ndarray] = {}
         self.lows = np.where(cob[:, 0] == EMPTY_KEY, np.int64(-1), cob[:, 0])
         self.peak_bytes = self.block.nbytes
+        self.max_words = 0    # widest row issued to the serial kernel
         self.n_consolidations = 0
         self.n_expansions = 0
         self.n_evictions = 0
@@ -449,14 +450,17 @@ class _PackedBatch:
                 lrid = np.array([local[int(r)] for r in ridx],
                                 dtype=np.int64)
                 order = np.lexsort((pos, lrid))
-                packed = np.zeros((len(packed_hit), self.cap),
-                                  dtype=np.uint32)
+                n_hit = len(packed_hit)
+                rows = -(-n_hit // 32) * 32   # bucket row counts for the jit
+                packed = np.zeros((rows, self.cap), dtype=np.uint32)
                 scatter_bits(packed, lrid[order], pos[order])
                 self.peak_bytes = max(self.peak_bytes,
-                                      self.block.nbytes + packed.nbytes)
+                                      self.block.nbytes + 2 * packed.nbytes)
                 rview = self.block[:, :self.cap]
+                cols = np.zeros_like(packed)
+                cols[:n_hit] = rview[packed_hit]
                 rview[packed_hit] = np.asarray(gf2_parallel_xor(
-                    jnp.asarray(rview[packed_hit]), jnp.asarray(packed)))
+                    jnp.asarray(cols), jnp.asarray(packed)))[:n_hit]
             else:
                 order = np.lexsort((pos, ridx))
                 scatter_xor_bits(self.block, ridx[order], pos[order])
@@ -556,6 +560,7 @@ class _PackedBatch:
         vslice[lv, lv >> 5] |= np.uint32(1) << (lv & 31).astype(np.uint32)
         C, W = B, cap + self.VW
         Cp, Wp = -(-C // 32) * 32, -(-W // 128) * 128
+        self.max_words = max(self.max_words, Wp)
         padded = np.zeros((Cp, Wp), dtype=np.uint32)
         padded[:C, :W] = self.block
         red, _, n_red = gf2_serial_reduce(jnp.asarray(padded[None]))
@@ -867,6 +872,7 @@ def reduce_dimension_packed(
     n_sweep_probes = 0
     exchange_bytes = 0
     peak_block_bytes = 0
+    max_block_words = 0
     # hand-rolled critical-path wall, kept ONLY to cross-check the
     # span-derived accounting (emitted as sim_wall_bookkeeping_s); the
     # reported sim_* stats come from critical_path(tl.spans) below
@@ -1205,6 +1211,9 @@ def reduce_dimension_packed(
         t_seq += sweep_cp
 
         peak_block_bytes = max(peak_block_bytes, batchblk.peak_bytes)
+        # block rows only widen; the serial kernel sees them padded
+        max_block_words = max(max_block_words, batchblk.block.shape[1],
+                              batchblk.max_words)
         n_consolidations += batchblk.n_consolidations
         n_expansions += batchblk.n_expansions
         n_evictions += batchblk.n_evictions
@@ -1316,6 +1325,7 @@ def reduce_dimension_packed(
     reg.counter("n_evictions").inc(n_evictions)
     reg.counter("n_consolidations").inc(n_consolidations)
     reg.gauge("peak_block_bytes").record_max(peak_block_bytes)
+    reg.gauge("max_block_words").record_max(max_block_words)
     reg.gauge("use_kernels").set(float(use_kernels))
     reg.gauge("n_shards").set(P)
     reg.counter("n_supersteps").inc(n_supersteps)

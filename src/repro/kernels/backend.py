@@ -8,10 +8,32 @@ explicitly.
 """
 from __future__ import annotations
 
+import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+# <checkout>/.jax_cache, fixed by this file's place in the tree
+# (src/repro/kernels/backend.py): the directory is part of the cache key,
+# so it must not move with the working directory
+_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def use_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own choice and is
+    left alone (returns ``None``); otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache``, whose path is returned.  Entry points call
+    this once; importing a library module never does.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+    return _CACHE_DIR
 
 
 def default_interpret() -> bool:

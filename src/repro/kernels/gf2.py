@@ -202,28 +202,22 @@ def unstack_wire_payloads(gathered: np.ndarray,
     return [out[k, :n] for k, n in enumerate(lens)]
 
 
-def _find_low_word(col: jnp.ndarray) -> jnp.ndarray:
-    """Index of first set bit of a packed (W,) uint32 column; NO_LOW if 0."""
-    nz = col != 0
-    any_nz = jnp.any(nz)
-    w = jnp.argmax(nz)                      # first non-zero word
-    word = col[w]
-    lsb = word & (~word + jnp.uint32(1))    # isolate lowest set bit
-    bit = jnp.asarray(jnp.bitwise_count(lsb - jnp.uint32(1)), jnp.int32)
-    return jnp.where(any_nz, jnp.asarray(w, jnp.int32) * 32 + bit,
-                     jnp.int32(NO_LOW))
+def _bit_positions(words: jnp.ndarray) -> jnp.ndarray:
+    """Per word of a uint32 array: the bit rank of its lowest set bit
+    (``32 * word index + bit``, word index along the last axis), NO_LOW
+    for zero words.  A min over the last axis is then the row's low.
+
+    Elementwise plus one int32 min, which is what Mosaic lowers: no
+    ``argmax``/``take_along_axis`` and no unsigned reductions."""
+    lsb = words & (~words + jnp.uint32(1))  # isolate lowest set bit
+    bit = jax.lax.population_count(lsb - jnp.uint32(1)).astype(jnp.int32)
+    widx = jax.lax.broadcasted_iota(jnp.int32, words.shape, words.ndim - 1)
+    return jnp.where(words != 0, widx * 32 + bit, jnp.int32(NO_LOW))
 
 
 def _find_low_kernel(cols_ref, lows_ref):
-    cols = cols_ref[...]                    # (C, W) uint32
-    nz = cols != 0
-    any_nz = jnp.any(nz, axis=1)
-    w = jnp.argmax(nz, axis=1)
-    word = jnp.take_along_axis(cols, w[:, None], axis=1)[:, 0]
-    lsb = word & (~word + jnp.uint32(1))
-    bit = jnp.asarray(jnp.bitwise_count(lsb - jnp.uint32(1)), jnp.int32)
-    lows_ref[...] = jnp.where(any_nz, jnp.asarray(w, jnp.int32) * 32 + bit,
-                              jnp.int32(NO_LOW))
+    lows_ref[...] = jnp.min(_bit_positions(cols_ref[...]), axis=1,
+                            keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
@@ -243,47 +237,53 @@ def gf2_find_low(cols: jnp.ndarray, block_c: int = 128,
         _find_low_kernel,
         grid=(cp // block_c,),
         in_specs=[pl.BlockSpec((block_c, w), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_c,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((cp,), jnp.int32),
+        out_specs=pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((cp, 1), jnp.int32),
         interpret=interpret,
     )(cols)
-    return lows[:c]
+    return lows[:c, 0]
 
 
 def _serial_reduce_kernel(in_ref, out_ref, lows_ref, reds_ref):
     """One block: in-order column reduction with collision XOR (paper serial
-    phase).  The block rides the loop carries as a value — refs are written
-    only at the top level, so the kernel lowers identically under Mosaic and
-    the interpreter (ref mutation inside ``while_loop`` has no interpret-mode
-    discharge rule)."""
+    phase).  The block lives in the output ref and rows are read and
+    written by dynamic row index (``pl.ds``); only the column being reduced
+    and the ``(1, C)`` lows vector ride the loop carries."""
     C = in_ref.shape[1]
-    lows0 = jnp.full((C,), NO_LOW, dtype=jnp.int32)
+    out_ref[...] = in_ref[...]
+    col_ids = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+
+    def row_low(col):
+        return jnp.min(_bit_positions(col))
 
     def reduce_one(c, state):
-        block, lows, n_red = state
-        earlier = jax.lax.broadcasted_iota(jnp.int32, (C,), 0) < c
+        lows, n_red = state
+        earlier = col_ids < c
+
+        def owner(low):
+            return (lows == low) & earlier & (low != jnp.int32(NO_LOW))
 
         def cond(st):
             _, low, _ = st
-            return jnp.any((lows == low) & earlier
-                           & (low != jnp.int32(NO_LOW)))
+            return jnp.any(owner(low))
 
         def body(st):
             col, low, n = st
-            j = jnp.argmax((lows == low) & earlier)
-            col = col ^ block[j]
-            return col, _find_low_word(col), n + 1
+            j = jnp.min(jnp.where(owner(low), col_ids, C))
+            col = col ^ out_ref[0, pl.ds(j, 1), :]
+            return col, row_low(col), n + 1
 
-        col0 = block[c]
+        col0 = out_ref[0, pl.ds(c, 1), :]
         col, low, n_red = jax.lax.while_loop(
-            cond, body, (col0, _find_low_word(col0), n_red))
-        return block.at[c].set(col), lows.at[c].set(low), n_red
+            cond, body, (col0, row_low(col0), n_red))
+        out_ref[0, pl.ds(c, 1), :] = col
+        return jnp.where(col_ids == c, low, lows), n_red
 
-    block, lows, n_red = jax.lax.fori_loop(
-        0, C, reduce_one, (in_ref[0], lows0, jnp.int32(0)))
-    out_ref[0] = block
+    lows, n_red = jax.lax.fori_loop(
+        0, C, reduce_one,
+        (jnp.full((1, C), NO_LOW, dtype=jnp.int32), jnp.int32(0)))
     lows_ref[0] = lows
-    reds_ref[0] = n_red
+    reds_ref[0] = jnp.full((1, 1), n_red, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -293,26 +293,29 @@ def gf2_serial_reduce(blocks: jnp.ndarray, interpret: Optional[bool] = None):
     blocks: (G, C, W) uint32 bit-packed columns, filtration order along C.
     Returns (reduced (G, C, W), lows (G, C) int32, n_reductions (G,) int32).
     After the call every block's non-empty columns have pairwise-distinct
-    lows — the invariant the paper's clearance step commits.
+    lows — the invariant the paper's clearance step commits.  Lows and
+    counts leave the kernel as ``(1, C)`` / ``(1, 1)`` blocks of rank-3
+    arrays, so every block spans its array's last two dims for any G.
     """
     interpret = resolve_interpret(interpret)
     g, c, w = blocks.shape
-    return pl.pallas_call(
+    red, lows, reds = pl.pallas_call(
         _serial_reduce_kernel,
         grid=(g,),
         in_specs=[pl.BlockSpec((1, c, w), lambda i: (i, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, c, w), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, c), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            pl.BlockSpec((1, 1, c), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g, c, w), jnp.uint32),
-            jax.ShapeDtypeStruct((g, c), jnp.int32),
-            jax.ShapeDtypeStruct((g,), jnp.int32),
+            jax.ShapeDtypeStruct((g, 1, c), jnp.int32),
+            jax.ShapeDtypeStruct((g, 1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(blocks)
+    return red, lows[:, 0, :], reds[:, 0, 0]
 
 
 def _parallel_xor_kernel(cols_ref, addends_ref, out_ref):

@@ -29,8 +29,13 @@ def _pairwise_kernel(x_ref, y_ref, o_ref):
     y = y_ref[...].astype(jnp.float32)
     xx = jnp.sum(x * x, axis=-1)[:, None]
     yy = jnp.sum(y * y, axis=-1)[None, :]
+    # HIGHEST: on a TPU the default f32 matmul is one bf16 pass, whose
+    # error on the cross term (~2e-2 on o3 at d = 9, v5e) is far beyond
+    # the f32 candidate margin of scale/tiles.py — the harvest then drops
+    # true edges.  HIGHEST stays within ~2e-6 there.
     xy = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, y, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     o_ref[...] = jnp.maximum(xx + yy - 2.0 * xy, 0.0)
 
 

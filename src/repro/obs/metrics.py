@@ -72,6 +72,8 @@ SCHEMA: Dict[str, MetricSpec] = {s.name: s for s in [
     _spec("peak_block_bytes", "gauge", "bytes",
           "high-water bytes of the packed bit block"),
     _spec("use_kernels", "gauge", "flag", "1 when Pallas kernels were used"),
+    _spec("max_block_words", "gauge", "words",
+          "widest packed block row (kernel path: as padded for the kernels)"),
     # -- distributed packed driver --
     _spec("n_shards", "gauge", "devices", "reduction shard count P"),
     _spec("n_supersteps", "counter", "steps", "fused supersteps executed"),
@@ -115,6 +117,8 @@ SCHEMA: Dict[str, MetricSpec] = {s.name: s for s in [
           "filtration result arrays: the (3n + 12 n_e) * 4 account realized"),
     _spec("tau_max_estimated", "gauge", "", "budget-derived tau_max"),
     _spec("sanitize_checks", "counter", "checks", "GF(2) sanitizer checks run"),
+    _spec("harvest_pallas", "gauge", "flag",
+          "tiled harvest backend: 1 = Pallas distance kernel, 0 = numpy"),
     _spec("per_device_peak_bytes", "gauge", "bytes",
           "sharded harvest: predicted per-device high-water"),
     _spec("per_device_base_bytes", "gauge", "bytes",

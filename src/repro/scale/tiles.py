@@ -96,11 +96,8 @@ class TileStats:
 
 def _resolve_backend(backend: str) -> str:
     if backend == "auto":
-        try:
-            import jax
-            return "pallas" if jax.default_backend() == "tpu" else "numpy"
-        except ImportError:
-            return "numpy"
+        import jax
+        return "pallas" if jax.default_backend() == "tpu" else "numpy"
     if backend not in ("numpy", "pallas"):
         raise ValueError(f"unknown tile backend {backend!r}")
     return backend

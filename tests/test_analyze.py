@@ -21,8 +21,8 @@ from repro.analyze.collectives import (
     verify_axes)
 from repro.analyze.lint import (
     BareExceptRule, DtypeBoundaryRule, HostSyncRule, RawFiltrationSortRule,
-    RawTimingRule, RefMutationRule, RetryWithoutBackoffRule, SpanLeakRule,
-    UnseededRngRule, default_rules, lint_file, lint_source)
+    RawTimingRule, RetryWithoutBackoffRule, SpanLeakRule, UnseededRngRule,
+    default_rules, lint_file, lint_source)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIXTURES = os.path.join(REPO, "tests", "fixtures", "analyze")
@@ -36,12 +36,6 @@ def lint_fixture(name, rule):
 # ---------------------------------------------------------------------------
 # Lint rules vs their negative fixtures
 # ---------------------------------------------------------------------------
-
-def test_ref_mutation_fixture_caught():
-    found = lint_fixture("bad_ref_mutation.py", RefMutationRule())
-    assert len(found) == 2          # the Assign and the AugAssign, not the
-    assert all(f.rule == "pallas-ref-mutation" for f in found)   # good kernel
-
 
 def test_host_sync_fixture_caught():
     found = lint_fixture("bad_host_sync.py", HostSyncRule())

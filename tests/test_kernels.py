@@ -42,6 +42,30 @@ def test_pairwise_dist_dtypes(dtype):
     assert np.allclose(np.diag(np.asarray(out)), 0.0, atol=atol)
 
 
+def test_pairwise_kernel_asks_for_f32_matmul():
+    """A TPU runs a default-precision f32 matmul as one bf16 pass, whose
+    error breaks the harvest's f32 candidate margin (interpret mode on a
+    CPU cannot show it): the kernel's cross term must ask for HIGHEST."""
+    import jax
+
+    def precisions(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn.params["precision"]
+            for p in eqn.params.values():
+                inner = getattr(p, "jaxpr", p)
+                if hasattr(inner, "eqns"):
+                    yield from precisions(inner)
+
+    closed = jax.make_jaxpr(
+        lambda x, y: pairwise_sq_dists(x, y, interpret=False))(
+        jnp.zeros((256, 9)), jnp.zeros((256, 9)))
+    found = list(precisions(closed.jaxpr))
+    assert found and all(
+        p == (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+        for p in found)
+
+
 def test_ops_pairwise_padding_path():
     """ops wrapper pads ragged row counts before tiling."""
     rng = np.random.default_rng(2)
@@ -55,10 +79,15 @@ def test_ops_pairwise_padding_path():
 # gf2
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("c,w", [(128, 8), (256, 64), (128, 1)])
+# the last three are the packed engine's shapes: rows bucketed to 32,
+# widths rounded up to 128 words
+@pytest.mark.parametrize("c,w", [(128, 8), (256, 64), (128, 1),
+                                 (32, 128), (96, 384), (128, 640)])
 def test_find_low_kernel(c, w):
     rng = np.random.default_rng(3)
     cols = rng.integers(0, 2**32, size=(c, w), dtype=np.uint32)
+    lead = rng.integers(0, w, size=c)         # zero words before the low
+    cols[np.arange(w)[None, :] < lead[:, None]] = 0
     cols[::7] = 0                             # some empty columns
     out = np.asarray(gf2_find_low(jnp.asarray(cols), block_c=128,
                                   interpret=True))
@@ -76,7 +105,8 @@ def test_find_low_hypothesis(seed):
     np.testing.assert_array_equal(out, kref.gf2_find_low_ref(cols))
 
 
-@pytest.mark.parametrize("g,c,w", [(1, 8, 4), (2, 16, 8), (4, 32, 2)])
+@pytest.mark.parametrize("g,c,w", [(1, 8, 4), (2, 16, 8), (4, 32, 2),
+                                   (1, 32, 128), (1, 128, 384)])
 def test_gf2_serial_reduce_kernel(g, c, w):
     rng = np.random.default_rng(4)
     # sparse-ish random columns so collisions actually happen
@@ -121,6 +151,42 @@ def test_gf2_reduction_preserves_span():
         return vecs
 
     assert span(blocks[0, :, 0]) == span(np.asarray(red)[0, :, 0])
+
+
+# ---------------------------------------------------------------------------
+# backend: persistent compilation cache
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_leaves_env_choice_alone(monkeypatch, tmp_path):
+    import jax
+
+    from repro.kernels.backend import use_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert use_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_dir_fixed_across_cwd_and_process(tmp_path):
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(repo, "src")
+    code = ("import jax\n"
+            "from repro.kernels.backend import use_compile_cache\n"
+            "print(use_compile_cache(), jax.config.jax_compilation_cache_dir)")
+    seen = set()
+    for cwd in (str(tmp_path), repo):
+        out = subprocess.run([sys.executable, "-c", code], cwd=cwd, env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        seen.add(out.stdout.strip().splitlines()[-1])
+    want = os.path.join(repo, ".jax_cache")
+    assert seen == {f"{want} {want}"}
 
 
 # ---------------------------------------------------------------------------
