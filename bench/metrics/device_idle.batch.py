@@ -1,0 +1,8 @@
+"""Device: the share of the traced window in which no operation ran on the
+chip, in percent (1 - union of op intervals / window)."""
+
+
+def read(run):
+    if run.trace is None or not run.trace.devices:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s() / run.trace.window_s)
