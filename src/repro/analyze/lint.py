@@ -26,9 +26,9 @@ code review away from hitting):
   phase breakdown.  Use :func:`repro.obs.trace.stopwatch` (always
   yields ``.elapsed``, records a span when tracing is active).
 * ``span-leak`` — ``span(...)`` / ``stopwatch(...)`` must be used as a
-  ``with`` context item (or via the ``traced()`` decorator).  A bare
-  call creates a context manager that is never entered/exited, so the
-  span silently never closes — especially on exception paths.
+  ``with`` context item.  A bare call creates a context manager that is
+  never entered/exited, so the span silently never closes — especially
+  on exception paths.
 * ``bare-except`` — recovery paths must catch *typed* faults
   (``TransientFault``, ``WireCorruption``, ``CheckpointCorruption``, …).
   A bare ``except:`` swallows ``KeyboardInterrupt``/``SystemExit`` and —
@@ -507,8 +507,7 @@ class SpanLeakRule(Rule):
                     relpath, node,
                     f"{chain[-1]}(...) not used as a `with` item; the span "
                     "never closes on the exception path — write "
-                    f"`with {chain[-1]}(...):` (or use the traced() "
-                    "decorator)"))
+                    f"`with {chain[-1]}(...):`"))
         return findings
 
 

@@ -301,7 +301,8 @@ def compute_ph(
                 with stopwatch("ph/h1") as sw:
                     if san is not None:
                         san.set_context(dim=1)
-                    adapter1 = make_h1_adapter(filt, sparse=sparse)
+                    with span("ph/adapter"):
+                        adapter1 = make_h1_adapter(filt, sparse=sparse)
                     cols1 = np.arange(filt.n_e - 1, -1, -1, dtype=np.int64)
                     res1 = _reduce(adapter1, cols1, mode=mode,
                                    cleared=h0.death_edges)
@@ -312,9 +313,12 @@ def compute_ph(
                 with stopwatch("ph/h2") as sw:
                     if san is not None:
                         san.set_context(dim=2)
-                    adapter2 = make_h2_adapter(filt, sparse=sparse)
-                    cols2 = h2_columns(filt, res1.pivot_lows, sparse=sparse,
-                                       memory_budget_bytes=memory_budget_bytes)
+                    with span("ph/adapter"):
+                        adapter2 = make_h2_adapter(filt, sparse=sparse)
+                    with span("ph/h2_columns"):
+                        cols2 = h2_columns(
+                            filt, res1.pivot_lows, sparse=sparse,
+                            memory_budget_bytes=memory_budget_bytes)
                     res2 = _reduce(adapter2, cols2, mode=mode)
                     diagrams[2] = res2.diagram()
                 reg.gauge("t_h2").set(sw.elapsed)

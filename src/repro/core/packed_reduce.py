@@ -99,7 +99,7 @@ from ..kernels.gf2 import (NO_LOW, find_low_np, scatter_bits,
                            stack_wire_payloads, unstack_wire_payloads)
 from ..launch.elastic import ShardSupervisor
 from ..obs.metrics import MetricsRegistry
-from ..obs.trace import Tracer, active_tracer, critical_path
+from ..obs.trace import Tracer, active_tracer, critical_path, span
 from ..resilience.faults import (TransientFault, active_injector,
                                  corrupt_payload, retry_with_backoff)
 from .pairing import EMPTY_KEY
@@ -193,6 +193,7 @@ class _PackedBatch:
         self.n_consolidations = 0
         self.n_expansions = 0
         self.n_evictions = 0
+        self.n_device_calls = 0   # gf2 kernel round trips
 
     # -- universe bookkeeping ------------------------------------------------
 
@@ -320,8 +321,10 @@ class _PackedBatch:
                 if pad:
                     sub = np.vstack(
                         [sub, np.zeros((pad, w), dtype=np.uint32)])
-                # analyze: allow[host-sync] lows gate the host serial pass; one bucketed sync per segment is the schedule
-                lb = np.asarray(gf2_find_low(jnp.asarray(sub)))[:len(rows)]
+                with span("gf2/find_low"):
+                    # analyze: allow[host-sync] lows gate the host serial pass; one bucketed sync per segment is the schedule
+                    lb = np.asarray(gf2_find_low(jnp.asarray(sub)))[:len(rows)]
+                self.n_device_calls += 1
             else:
                 lb = find_low_np(sub)
             k = np.where(lb == NO_LOW, EMPTY_KEY,
@@ -459,8 +462,10 @@ class _PackedBatch:
                 rview = self.block[:, :self.cap]
                 cols = np.zeros_like(packed)
                 cols[:n_hit] = rview[packed_hit]
-                rview[packed_hit] = np.asarray(gf2_parallel_xor(
-                    jnp.asarray(cols), jnp.asarray(packed)))[:n_hit]
+                with span("gf2/xor"):
+                    rview[packed_hit] = np.asarray(gf2_parallel_xor(
+                        jnp.asarray(cols), jnp.asarray(packed)))[:n_hit]
+                self.n_device_calls += 1
             else:
                 order = np.lexsort((pos, ridx))
                 scatter_xor_bits(self.block, ridx[order], pos[order])
@@ -521,19 +526,20 @@ class _PackedBatch:
             row_iter = range(self.B)
         else:
             row_iter = [int(r) for r in rows]
-        low_to_row: Dict[int, int] = {}
-        for c in row_iter:
-            low = int(self.lows[c])
-            while low >= 0:
-                j = low_to_row.get(low)
-                if j is None:
-                    break
-                n_red += 1
-                changed[c] = True
-                low = self._absorb(c, j, gens, ids_int)
-            self.lows[c] = low
-            if low >= 0:
-                low_to_row[low] = c
+        with span("reduce/serial"):
+            low_to_row: Dict[int, int] = {}
+            for c in row_iter:
+                low = int(self.lows[c])
+                while low >= 0:
+                    j = low_to_row.get(low)
+                    if j is None:
+                        break
+                    n_red += 1
+                    changed[c] = True
+                    low = self._absorb(c, j, gens, ids_int)
+                self.lows[c] = low
+                if low >= 0:
+                    low_to_row[low] = c
         return n_red, np.array(sorted(changed), dtype=np.int64)
 
     def _serial_kernel_prepass(self, gens: List[Dict[int, int]],
@@ -563,9 +569,11 @@ class _PackedBatch:
         self.max_words = max(self.max_words, Wp)
         padded = np.zeros((Cp, Wp), dtype=np.uint32)
         padded[:C, :W] = self.block
-        red, _, n_red = gf2_serial_reduce(jnp.asarray(padded[None]))
-        self.block[...] = np.asarray(red)[0, :C, :W]
-        n_red = int(np.asarray(n_red)[0])
+        with span("gf2/serial"):
+            red, _, n_red = gf2_serial_reduce(jnp.asarray(padded[None]))
+            self.block[...] = np.asarray(red)[0, :C, :W]
+            n_red = int(np.asarray(n_red)[0])
+        self.n_device_calls += 1
         if n_red == 0:
             vslice[...] = 0
             return 0
@@ -790,9 +798,10 @@ def reduce_dimension_packed(
     carries its lane (shard) and superstep, so a run under
     ``compute_ph(trace=...)`` renders as P parallel device lanes — and
     ``sim_wall_s`` is *derived* from that span timeline
-    (:func:`repro.obs.trace.critical_path`); the legacy hand-rolled
-    accounting is kept only as ``sim_wall_bookkeeping_s`` so the two can be
-    cross-checked (``tests/test_obs.py`` asserts they agree at P = 4).
+    (:func:`repro.obs.trace.critical_path`).  Leaf spans of the host work
+    and of each gf2 kernel round trip (``reduce/probe``, ``gf2/xor``, ...)
+    go to the caller's tracer only and carry no ``step``, so they never
+    enter that accounting.
     """
     san = active_sanitizer()
     # local timeline: always on (sim_wall is derived from it), forwarding
@@ -873,15 +882,13 @@ def reduce_dimension_packed(
     exchange_bytes = 0
     peak_block_bytes = 0
     max_block_words = 0
-    # hand-rolled critical-path wall, kept ONLY to cross-check the
-    # span-derived accounting (emitted as sim_wall_bookkeeping_s); the
-    # reported sim_* stats come from critical_path(tl.spans) below
-    sim_wall_book = 0.0
+    n_device_calls = 0
     reg = MetricsRegistry()
     queue = clearing_filter(column_ids, cleared)
     eff_batch = batch_size
     if len(queue):
-        cob0 = adapter.cobdy(queue[:min(batch_size, len(queue))])
+        with span("reduce/cobdy"):
+            cob0 = adapter.cobdy(queue[:min(batch_size, len(queue))])
         eff_batch = _budgeted_batch_size(batch_size, cob0.shape[1],
                                          store_budget_bytes)
 
@@ -977,7 +984,8 @@ def reduce_dimension_packed(
         t_slice = np.zeros(max(n_slices, 1))
         t_seq = 0.0
         with tl.span("reduce/fused", step=step, weights=wt) as sp:
-            cob = adapter.cobdy(ids_arr)
+            with span("reduce/cobdy"):
+                cob = adapter.cobdy(ids_arr)
             if seed_gens:
                 # warm restart: seeded rows start from their recorded
                 # residual (a valid left-to-right partial reduction state)
@@ -1007,12 +1015,14 @@ def reduce_dimension_packed(
             # the replica (P > 1) — complete up to the last exchange
             # round — or the store
             lows0 = np.where(cob[:, 0] == EMPTY_KEY, np.int64(-1), cob[:, 0])
-            addends, owners, owner_gens = \
-                lookup_store.lookup_addends_batched(lows0, ids_arr)
+            with span("reduce/probe"):
+                addends, owners, owner_gens = \
+                    lookup_store.lookup_addends_batched(lows0, ids_arr)
             addend_lows = lows0
-            batchblk = _PackedBatch(
-                cob, [a for a in addends if a is not None], use_kernels,
-                cache=cache)
+            with span("reduce/pack"):
+                batchblk = _PackedBatch(
+                    cob, [a for a in addends if a is not None], use_kernels,
+                    cache=cache)
         t_fused += sp.dur
 
         probe = np.zeros(B, dtype=bool)   # rows whose low moved since probe
@@ -1022,13 +1032,15 @@ def reduce_dimension_packed(
                 if hit:
                     n_rounds += 1
                     n_reductions += len(hit)
-                    for i in hit:
-                        o = int(owners[i])
-                        gens[i][o] = gens[i].get(o, 0) + 1
-                        for g in owner_gens[i]:
-                            g = int(g)
-                            gens[i][g] = gens[i].get(g, 0) + 1
-                    batchblk.xor_addends(hit, addends, addend_lows)
+                    with span("reduce/gens"):
+                        for i in hit:
+                            o = int(owners[i])
+                            gens[i][o] = gens[i].get(o, 0) + 1
+                            for g in owner_gens[i]:
+                                g = int(g)
+                                gens[i][g] = gens[i].get(g, 0) + 1
+                    with span("reduce/xor"):
+                        batchblk.xor_addends(hit, addends, addend_lows)
                     probe[hit] = batchblk.lows[hit] >= 0
             t_fused += sp.dur
 
@@ -1061,8 +1073,10 @@ def reduce_dimension_packed(
             with tl.span("reduce/fused", step=step, weights=wt) as sp:
                 probe_lows = np.where(probe, batchblk.lows, -1)
                 probe[:] = False
-                addends, owners, owner_gens = \
-                    lookup_store.lookup_addends_batched(probe_lows, ids_arr)
+                with span("reduce/probe"):
+                    addends, owners, owner_gens = \
+                        lookup_store.lookup_addends_batched(probe_lows,
+                                                            ids_arr)
                 addend_lows = probe_lows
             t_fused += sp.dur
 
@@ -1110,7 +1124,6 @@ def reduce_dimension_packed(
         # *this-superstep* pivots it actually absorbed (a device learns
         # the earlier stable lows from a tiny broadcast and otherwise
         # sweeps + commits concurrently) — ``deps`` records that DAG ----
-        t_sweep = np.zeros(max(n_slices, 1))
         deps: List[set] = [set() for _ in range(max(n_slices, 1))]
         for k in range(n_slices):
             with tl.span("reduce/sweep", lane=k, step=step) as sw_sp:
@@ -1133,32 +1146,36 @@ def reduce_dimension_packed(
                             break
                         sl_lows[~cand] = -1
                         n_sweep_probes += 1
-                        adds, owns, ogens = \
-                            store.lookup_addends_batched(sl_lows, sids)
+                        with span("reduce/probe"):
+                            adds, owns, ogens = \
+                                store.lookup_addends_batched(sl_lows, sids)
                         dirty[:] = False
                         hit_local = [i for i in range(len(sids))
                                      if adds[i] is not None]
                         if hit_local:
                             n_rounds += 1
                             n_reductions += len(hit_local)
-                            for i in hit_local:
-                                c = s0 + i
-                                o = int(owns[i])
-                                gens[c][o] = gens[c].get(o, 0) + 1
-                                for g in ogens[i]:
-                                    g = int(g)
-                                    gens[c][g] = gens[c].get(g, 0) + 1
-                                src = pending.get(int(sl_lows[i]))
-                                if src is not None \
-                                        and src[1] == n_supersteps:
-                                    deps[k].add(src[0])
+                            with span("reduce/gens"):
+                                for i in hit_local:
+                                    c = s0 + i
+                                    o = int(owns[i])
+                                    gens[c][o] = gens[c].get(o, 0) + 1
+                                    for g in ogens[i]:
+                                        g = int(g)
+                                        gens[c][g] = gens[c].get(g, 0) + 1
+                                    src = pending.get(int(sl_lows[i]))
+                                    if src is not None \
+                                            and src[1] == n_supersteps:
+                                        deps[k].add(src[0])
                             full_adds: List[Optional[np.ndarray]] = [None] * B
                             full_lows = np.full(B, -1, dtype=np.int64)
                             for i in hit_local:
                                 full_adds[s0 + i] = adds[i]
                                 full_lows[s0 + i] = sl_lows[i]
-                            batchblk.xor_addends([s0 + i for i in hit_local],
-                                                 full_adds, full_lows)
+                            with span("reduce/xor"):
+                                batchblk.xor_addends(
+                                    [s0 + i for i in hit_local],
+                                    full_adds, full_lows)
                             dirty[hit_local] = True
                         cur = batchblk.lows[s0:s1]
                         nz = cur[cur >= 0]
@@ -1171,13 +1188,14 @@ def reduce_dimension_packed(
 
                 log_mark = len(commit_log) \
                     if (P > 1 and commit_log is not None) else 0
-                clearance_commit(
-                    store, adapter, sids, batchblk.lows[s0:s1],
-                    gens[s0:s1],
-                    lambda rr, rows=rows: batchblk.unpack(
-                        rows[np.asarray(rr, dtype=np.int64)]),
-                    pairs, essentials, essential_ids=essential_ids,
-                    essential_log=essential_log)
+                with span("reduce/commit"):
+                    clearance_commit(
+                        store, adapter, sids, batchblk.lows[s0:s1],
+                        gens[s0:s1],
+                        lambda rr, rows=rows: batchblk.unpack(
+                            rows[np.asarray(rr, dtype=np.int64)]),
+                        pairs, essentials, essential_ids=essential_ids,
+                        essential_log=essential_log)
                 if P > 1 and len(commit_log) > log_mark:
                     # drain this slice's commits straight into its shard's
                     # wire backlog; their lows are pending until the next
@@ -1198,17 +1216,6 @@ def reduce_dimension_packed(
                 # the dep DAG is known only now — amend the span so the
                 # timeline alone reconstructs the sweep critical path
                 sw_sp.set(deps=tuple(sorted(deps[k])))
-            t_sweep[k] += sw_sp.dur
-
-        # critical path over the sweep DAG: finish(k) = t_sweep[k] +
-        # max finish over the slices k absorbed from (deps point strictly
-        # backward, so one forward pass is the longest-path DP)
-        finish = np.zeros(max(n_slices, 1))
-        for k in range(n_slices):
-            dep_finish = max((finish[d] for d in deps[k]), default=0.0)
-            finish[k] = dep_finish + t_sweep[k]
-        sweep_cp = float(finish[:max(n_slices, 1)].max()) if n_slices else 0.0
-        t_seq += sweep_cp
 
         peak_block_bytes = max(peak_block_bytes, batchblk.peak_bytes)
         # block rows only widen; the serial kernel sees them padded
@@ -1217,11 +1224,11 @@ def reduce_dimension_packed(
         n_consolidations += batchblk.n_consolidations
         n_expansions += batchblk.n_expansions
         n_evictions += batchblk.n_evictions
+        n_device_calls += batchblk.n_device_calls
 
         frac = np.asarray(wt, dtype=np.float64)
         step_conc = float(np.max(t_fused * frac + t_slice[:n_slices]))
         reg.histogram("superstep_conc_s").observe(step_conc)
-        sim_wall_book += step_conc + t_seq
 
         # ---- pivot exchange (every ``exchange_every`` supersteps, and
         # skipped once the queue is drained — the replica is never read
@@ -1233,14 +1240,12 @@ def reduce_dimension_packed(
                 and n_supersteps % exchange_every == 0
                 and any(shard_logs)):
             n_exchange_rounds += 1
-            t_enc = np.zeros(P)
             payloads = []
             shipped_lows: List[List[int]] = []
             for k in range(P):
-                with tl.span("reduce/encode", lane=k, step=step) as sp:
+                with tl.span("reduce/encode", lane=k, step=step):
                     payloads.append(encode_commit_delta(shard_logs[k]))
                 shipped_lows.append([r["low"] for r in shard_logs[k]])
-                t_enc[k] = sp.dur
             # wire-level faults: each payload's delivery gets a bounded
             # retry with deterministic jittered backoff (the schedule is
             # accounted, not slept — this transport is host-simulated); a
@@ -1293,14 +1298,12 @@ def reduce_dimension_packed(
                         delivered[k] = False
             wire = sum(p.nbytes for p in payloads)
             exchange_bytes += wire
-            with tl.span("reduce/exchange", step=step,
-                         bytes=int(wire)) as sp:
+            with tl.span("reduce/exchange", step=step, bytes=int(wire)):
                 for payload in exchange(payloads):
                     for rec in decode_commit_delta(payload):
                         replica.install(rec["low"], rec["col_id"],
                                         rec["mode"], rec["column"],
                                         rec["gens"])
-            sim_wall_book += float(t_enc.max()) + sp.dur
             for k in range(P):
                 if delivered[k]:
                     for low in shipped_lows[k]:
@@ -1309,8 +1312,7 @@ def reduce_dimension_packed(
 
     if san is not None:
         san.set_context(superstep=None, batch=None, slice=None)
-    # the reported sim walls are DERIVED from the span timeline — the
-    # bookkeeping above survives only as its cross-check
+    # the reported sim walls are derived from the span timeline
     cp = critical_path(tl.spans)
     reg.counter("n_columns").inc(len(queue))
     reg.counter("n_reductions").inc(n_reductions)
@@ -1327,6 +1329,7 @@ def reduce_dimension_packed(
     reg.gauge("peak_block_bytes").record_max(peak_block_bytes)
     reg.gauge("max_block_words").record_max(max_block_words)
     reg.gauge("use_kernels").set(float(use_kernels))
+    reg.counter("n_device_calls").inc(n_device_calls)
     reg.gauge("n_shards").set(P)
     reg.counter("n_supersteps").inc(n_supersteps)
     reg.counter("n_exchange_rounds").inc(n_exchange_rounds)
@@ -1344,6 +1347,5 @@ def reduce_dimension_packed(
         reg.counter("resilience_n_wire_corruptions").inc(n_wire_corruptions)
     for key, val in cp.items():
         reg.gauge(key).set(val)
-    reg.gauge("sim_wall_bookkeeping_s").set(sim_wall_book)
     reg.update_from(cache.stats())
     return finalize_result(pairs, essentials, essential_ids, reg.as_stats())

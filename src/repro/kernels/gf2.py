@@ -240,6 +240,7 @@ def gf2_find_low(cols: jnp.ndarray, block_c: int = 128,
         out_specs=pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((cp, 1), jnp.int32),
         interpret=interpret,
+        name="gf2_find_low",
     )(cols)
     return lows[:c, 0]
 
@@ -314,6 +315,7 @@ def gf2_serial_reduce(blocks: jnp.ndarray, interpret: Optional[bool] = None):
             jax.ShapeDtypeStruct((g, 1, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="gf2_serial_reduce",
     )(blocks)
     return red, lows[:, 0, :], reds[:, 0, 0]
 
@@ -347,5 +349,6 @@ def gf2_parallel_xor(cols: jnp.ndarray, addends: jnp.ndarray,
         out_specs=pl.BlockSpec((block_c, w), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((cp, w), jnp.uint32),
         interpret=interpret,
+        name="gf2_parallel_xor",
     )(cols, addends)
     return out[:c]

@@ -66,5 +66,6 @@ def pairwise_sq_dists(x: jnp.ndarray, y: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((x.shape[0], y.shape[0]),
                                        jnp.float32),
         interpret=interpret,
+        name="pairwise_sq_dists",
     )(x, y)
     return out[:m, :n]

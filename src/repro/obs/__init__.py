@@ -13,11 +13,11 @@ hottest core modules without cycles, and from environments without jax.
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, MetricSpec,
                       SCHEMA, schema_markdown)
 from .trace import (Span, Tracer, active_tracer, chrome_trace, coverage,
-                    critical_path, span, stopwatch, traced, tracing)
+                    critical_path, span, stopwatch, tracing)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "MetricSpec",
     "SCHEMA", "schema_markdown",
     "Span", "Tracer", "active_tracer", "chrome_trace", "coverage",
-    "critical_path", "span", "stopwatch", "traced", "tracing",
+    "critical_path", "span", "stopwatch", "tracing",
 ]

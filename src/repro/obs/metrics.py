@@ -72,6 +72,9 @@ SCHEMA: Dict[str, MetricSpec] = {s.name: s for s in [
     _spec("peak_block_bytes", "gauge", "bytes",
           "high-water bytes of the packed bit block"),
     _spec("use_kernels", "gauge", "flag", "1 when Pallas kernels were used"),
+    _spec("n_device_calls", "counter", "calls",
+          "gf2 kernel round trips (host array to device and back); "
+          "0 on the host path"),
     _spec("max_block_words", "gauge", "words",
           "widest packed block row (kernel path: as padded for the kernels)"),
     # -- distributed packed driver --
@@ -90,8 +93,6 @@ SCHEMA: Dict[str, MetricSpec] = {s.name: s for s in [
     _spec("sim_sweep_s", "gauge", "s", "commit-sweep DAG share of sim wall"),
     _spec("sim_sync_s", "gauge", "s",
           "tournament + exchange share of sim wall"),
-    _spec("sim_wall_bookkeeping_s", "gauge", "s",
-          "hand-rolled sim wall kept for cross-checking the span-derived one"),
     _spec("superstep_conc_s", "histogram", "s",
           "per-superstep concurrent-phase wall distribution"),
     # -- shared pivot cache --

@@ -25,23 +25,27 @@ Naming convention (see ``docs/observability.md``): ``area/what`` — e.g.
 
 The exported JSON loads directly in https://ui.perfetto.dev (or
 ``chrome://tracing``): one process, thread 0 is the host track, thread
-``k + 1`` is ``device:k``.  Setting ``REPRO_TRACE_JAX=1`` additionally
-wraps every live span in a ``jax.profiler.TraceAnnotation`` so the same
-names show up inside XLA profiles.
+``k + 1`` is ``device:k``.
+
+The profiler bridge: a tracer with ``bridge=True`` (``REPRO_TRACE_JAX=1``
+for tracers :func:`tracing` creates) wraps every live span, stopwatches
+included, in a ``jax.profiler.TraceAnnotation`` named by the span name
+alone, so the spans land on the device trace's clock.  A tracer built with
+``forward_to=t`` takes ``bridge`` from ``t``; forwarding a closed span
+records it and annotates nothing, so each span is annotated once.
 """
 from __future__ import annotations
 
 import contextlib
-import functools
 import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional, Union
+from typing import Any, Dict, Iterable, Iterator, List, Optional, Union
 
 __all__ = [
-    "Span", "Tracer", "active_tracer", "span", "stopwatch", "traced",
-    "tracing", "critical_path", "chrome_trace", "coverage",
+    "Span", "Tracer", "active_tracer", "span", "stopwatch", "tracing",
+    "critical_path", "chrome_trace", "coverage",
 ]
 
 _CLOCK = time.perf_counter        # analyze: allow[raw-timing] the one blessed clock
@@ -116,9 +120,7 @@ class _SpanCtx:
         tr = self._tracer
         tr._open_enter(self)
         if tr.bridge:
-            self._ann = _jax_annotation(self.name)
-            if self._ann is not None:
-                self._ann.__enter__()
+            self._ann = _enter_annotation(self.name)
         self.t0 = _CLOCK()
         return self
 
@@ -142,6 +144,14 @@ def _jax_annotation(name: str):
         return None
 
 
+def _enter_annotation(name: str):
+    """Open the profiler annotation of a live span (``None``: no bridge)."""
+    ann = _jax_annotation(name)
+    if ann is not None:
+        ann.__enter__()
+    return ann
+
+
 class Tracer:
     """Thread-safe span collector.
 
@@ -149,13 +159,15 @@ class Tracer:
     ``packed_reduce`` keeps an always-on local timeline (its simulated wall
     is *derived* from it) and forwards into the user's tracer when one is
     active, so one measurement feeds both accountings.
-    ``bridge=True`` wraps live spans in ``jax.profiler.TraceAnnotation``.
+    ``bridge=True`` wraps live spans in ``jax.profiler.TraceAnnotation``;
+    a forwarding tracer bridges whenever its target does.
     """
 
     def __init__(self, forward_to: Optional["Tracer"] = None,
                  bridge: bool = False):
         self.spans: List[Span] = []
-        self.bridge = bridge
+        self.bridge = bridge or (forward_to is not None
+                                 and forward_to.bridge)
         self._forward = forward_to
         self._lock = threading.Lock()
         self._open: Dict[int, str] = {}     # id(ctx) -> name, for balance
@@ -304,12 +316,13 @@ def span(name: str, lane: Optional[int] = None,
 
 
 class _Stopwatch:
-    """Always-on timer that doubles as a span when tracing is active.
+    """Always-on timer that doubles as a span when tracing is active (and
+    as a profiler annotation when the active tracer bridges).
 
     ``.elapsed`` is valid after exit (including the exception path).
     """
 
-    __slots__ = ("name", "lane", "attrs", "t0", "elapsed")
+    __slots__ = ("name", "lane", "attrs", "t0", "elapsed", "_ann")
 
     def __init__(self, name: str, lane: Optional[int], attrs: Dict[str, Any]):
         self.name = name
@@ -317,14 +330,21 @@ class _Stopwatch:
         self.attrs = attrs
         self.t0 = 0.0
         self.elapsed = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_Stopwatch":
+        tr = _active
+        if tr is not None and tr.bridge:
+            self._ann = _enter_annotation(self.name)
         self.t0 = _CLOCK()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = _CLOCK()
         self.elapsed = t1 - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         tr = _active
         if tr is not None:
             tr.record(Span(self.name, self.lane, self.t0, t1, self.attrs))
@@ -335,20 +355,6 @@ def stopwatch(name: str, lane: Optional[int] = None,
               **attrs: Any) -> _Stopwatch:
     """``with stopwatch("ph/h1") as sw: ...`` then read ``sw.elapsed``."""
     return _Stopwatch(name, lane, attrs)
-
-
-def traced(name: Optional[str] = None, lane: Optional[int] = None,
-           **attrs: Any) -> Callable:
-    """Decorator form of :func:`span` (defaults to the function qualname)."""
-    def deco(fn: Callable) -> Callable:
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*args: Any, **kwargs: Any) -> Any:
-            with span(label, lane=lane, **attrs):
-                return fn(*args, **kwargs)
-        return wrapper
-    return deco
 
 
 @contextlib.contextmanager

@@ -317,11 +317,13 @@ def iter_tile_edges(
             if backend == "pallas":
                 with span("harvest/tile", tile=f"{si},{sj}",
                           backend="pallas"):
-                    # analyze: allow[host-sync] one gather per tile is the streaming contract; the f64 refine consumes it on host
-                    d2_32 = np.asarray(pairwise_sq_dists(
-                        pts32[si:ei], pts32[sj:ej], interpret=interpret))
-                    return _refine_f32_tile(d2_32, points, sq, si, ei,
-                                            sj, ej, tau_max, thr32, stats)
+                    with span("harvest/fetch"):
+                        # analyze: allow[host-sync] one gather per tile is the streaming contract; the f64 refine consumes it on host
+                        d2_32 = np.asarray(pairwise_sq_dists(
+                            pts32[si:ei], pts32[sj:ej], interpret=interpret))
+                    with span("harvest/refine"):
+                        return _refine_f32_tile(d2_32, points, sq, si, ei,
+                                                sj, ej, tau_max, thr32, stats)
             with span("harvest/tile", tile=f"{si},{sj}", backend="numpy"):
                 return _harvest_points_tile(points, sq, si, ei, sj, ej,
                                             tau_max, stats)
@@ -427,9 +429,10 @@ def build_filtration_tiled(
                                  tile_m=tile_m, tile_n=tile_n,
                                  backend=backend, interpret=interpret,
                                  stats=stats)
-    filt = filtration_from_edges(stats.n, iu, ju, lens, tau_max,
-                                 presorted=True,
-                                 with_dense_order=with_dense_order)
+    with span("harvest/build"):
+        filt = filtration_from_edges(stats.n, iu, ju, lens, tau_max,
+                                     presorted=True,
+                                     with_dense_order=with_dense_order)
     stats.base_memory_bytes = filt.base_memory_bytes()
     if return_stats:
         return filt, stats
