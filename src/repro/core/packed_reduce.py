@@ -13,14 +13,15 @@ block for its whole reduction:
   batch's coboundaries plus the first round of gathered addends becomes the
   block's bit-space (``kernels.gf2.scatter_bits``): key ``universe[i]``
   lives at bit ``i``, so ascending keys are ascending ranks, a
-  first-set-bit scan (``gf2_find_low`` / ``find_low_np``) *is* the engine's
-  ``low``, and one 32-word VREG XOR covers 32,768 matrix entries;
+  first-set-bit scan (inside the gf2 kernels, ``find_low_np`` on host)
+  *is* the engine's ``low``, and one 32-word VREG XOR covers 32,768 matrix
+  entries;
 * **parallel phase** — one :meth:`PivotStore.lookup_addends_batched` probe
   per round (one ``owner_of_low`` / ``min_cobdy`` / ``cobdy`` call for the
   whole batch), then the hit rows absorb their gathered committed-pivot
   addends: an in-place bit scatter-XOR on host, ``gf2_parallel_xor`` on the
-  gathered addend block on TPU.  Only rows whose low moved are probed
-  again;
+  gathered addend block on TPU, which returns the rows' new lows with their
+  sums.  Only rows whose low moved are probed again;
 * **segmented growth vs eviction** — an addend with keys outside the
   bit-space either *expands* the space (the new keys append as a fresh
   word-aligned segment; no re-ranking, lows become a min over per-segment
@@ -194,6 +195,7 @@ class _PackedBatch:
         self.n_expansions = 0
         self.n_evictions = 0
         self.n_device_calls = 0   # gf2 kernel round trips
+        self.n_kernel_lows = 0    # rows whose low came back with a gf2 call
 
     # -- universe bookkeeping ------------------------------------------------
 
@@ -221,9 +223,10 @@ class _PackedBatch:
 
     def consolidate(self) -> None:
         """Merge all segments into one sorted universe (one global remap).
-        The kernel path runs consolidated always: ``gf2_find_low`` /
-        ``gf2_serial_reduce`` read the first set *bit*, which equals the
-        min *key* only in a single globally-sorted bit-space."""
+        The kernel path runs consolidated always: the lows
+        ``gf2_parallel_xor`` / ``gf2_serial_reduce`` return are first set
+        *bits*, which equal the min *key* only in a single globally-sorted
+        bit-space."""
         if len(self.segs) == 1:
             return
         self.n_consolidations += 1
@@ -302,8 +305,10 @@ class _PackedBatch:
     # -- lows ----------------------------------------------------------------
 
     def refresh_lows(self, rows: np.ndarray) -> None:
-        """Recompute ``lows[rows]`` (packed rows) as the min key over
-        per-segment find-lows (``gf2_find_low`` on the kernel path)."""
+        """Recompute ``lows[rows]`` (packed rows, host path) as the min key
+        over per-segment find-lows.  The kernel path never calls it: each
+        gf2 call returns the lows of the rows it wrote
+        (:meth:`_set_kernel_lows`)."""
         rows = np.asarray(rows, dtype=np.int64)
         if not rows.size:
             return
@@ -312,25 +317,23 @@ class _PackedBatch:
             if not len(seg):
                 continue
             w = _words(len(seg), self.use_kernels)
-            sub = self.block[rows, off:off + w]
-            if self.use_kernels:
-                import jax.numpy as jnp
-
-                from ..kernels.gf2 import gf2_find_low
-                pad = (-len(rows)) % 32   # bucket row counts for the jit
-                if pad:
-                    sub = np.vstack(
-                        [sub, np.zeros((pad, w), dtype=np.uint32)])
-                with span("gf2/find_low"):
-                    # analyze: allow[host-sync] lows gate the host serial pass; one bucketed sync per segment is the schedule
-                    lb = np.asarray(gf2_find_low(jnp.asarray(sub)))[:len(rows)]
-                self.n_device_calls += 1
-            else:
-                lb = find_low_np(sub)
+            lb = find_low_np(self.block[rows, off:off + w])
             k = np.where(lb == NO_LOW, EMPTY_KEY,
                          seg[np.minimum(lb, len(seg) - 1)])
             best = np.minimum(best, k)
         self.lows[rows] = np.where(best == EMPTY_KEY, -1, best)
+
+    def _set_kernel_lows(self, rows: List[int], lb: np.ndarray) -> None:
+        """Set ``lows[rows]`` from the first-set-bit ranks a gf2 kernel
+        returned on the kernel path's single segment: -1 for NO_LOW and for
+        ranks past the universe (zero slack words, or the V-words of an
+        R-empty row)."""
+        seg = self.segs[0]
+        inside = lb < len(seg)
+        keys = np.full(len(lb), -1, dtype=np.int64)
+        keys[inside] = seg[lb[inside]]
+        self.lows[rows] = keys
+        self.n_kernel_lows += len(rows)
 
     def _row_low(self, c: int) -> int:
         best = -1
@@ -446,6 +449,7 @@ class _PackedBatch:
                 packed_hit = list(memo_rows)
         if packed_hit:
             if self.use_kernels:
+                import jax
                 import jax.numpy as jnp
 
                 from ..kernels.gf2 import gf2_parallel_xor
@@ -463,13 +467,16 @@ class _PackedBatch:
                 cols = np.zeros_like(packed)
                 cols[:n_hit] = rview[packed_hit]
                 with span("gf2/xor"):
-                    rview[packed_hit] = np.asarray(gf2_parallel_xor(
-                        jnp.asarray(cols), jnp.asarray(packed)))[:n_hit]
+                    # analyze: allow[host-sync] lows gate the host serial pass; one sync brings the sum and its lows back together
+                    xored, lb = jax.device_get(gf2_parallel_xor(
+                        jnp.asarray(cols), jnp.asarray(packed)))
+                    rview[packed_hit] = xored[:n_hit]
                 self.n_device_calls += 1
+                self._set_kernel_lows(packed_hit, lb[:n_hit])
             else:
                 order = np.lexsort((pos, ridx))
                 scatter_xor_bits(self.block, ridx[order], pos[order])
-            self.refresh_lows(np.asarray(packed_hit, dtype=np.int64))
+                self.refresh_lows(np.asarray(packed_hit, dtype=np.int64))
         for i in scalar_hit:
             merged = merge_cancel(self.scalar[i], addends[i])
             self.scalar[i] = merged
@@ -550,7 +557,10 @@ class _PackedBatch:
         the V bits name each row's absorbed mates afterwards (scalar rows'
         block rows are zero, hence inert; zero slack words between the R
         segment and the V-words are skipped by the kernel's find-low; and
-        V-rank collisions only ever involve R-empty rows)."""
+        V-rank collisions only ever involve R-empty rows).  The kernel's
+        own lows become the touched rows' low keys: a rank past the R
+        universe means the row's R part is empty."""
+        import jax
         import jax.numpy as jnp
 
         from ..kernels.gf2 import gf2_serial_reduce
@@ -570,9 +580,10 @@ class _PackedBatch:
         padded = np.zeros((Cp, Wp), dtype=np.uint32)
         padded[:C, :W] = self.block
         with span("gf2/serial"):
-            red, _, n_red = gf2_serial_reduce(jnp.asarray(padded[None]))
-            self.block[...] = np.asarray(red)[0, :C, :W]
-            n_red = int(np.asarray(n_red)[0])
+            red, lb, n_red = jax.device_get(
+                gf2_serial_reduce(jnp.asarray(padded[None])))
+            self.block[...] = red[0, :C, :W]
+            n_red = int(n_red[0])
         self.n_device_calls += 1
         if n_red == 0:
             vslice[...] = 0
@@ -598,7 +609,7 @@ class _PackedBatch:
             gens[i] = newg
         vslice[...] = 0
         if touched:
-            self.refresh_lows(np.array(touched, dtype=np.int64))
+            self._set_kernel_lows(touched, lb[0, touched])
         return n_red
 
     # -- clearance -----------------------------------------------------------
@@ -883,6 +894,7 @@ def reduce_dimension_packed(
     peak_block_bytes = 0
     max_block_words = 0
     n_device_calls = 0
+    n_kernel_lows = 0
     reg = MetricsRegistry()
     queue = clearing_filter(column_ids, cleared)
     eff_batch = batch_size
@@ -1225,6 +1237,7 @@ def reduce_dimension_packed(
         n_expansions += batchblk.n_expansions
         n_evictions += batchblk.n_evictions
         n_device_calls += batchblk.n_device_calls
+        n_kernel_lows += batchblk.n_kernel_lows
 
         frac = np.asarray(wt, dtype=np.float64)
         step_conc = float(np.max(t_fused * frac + t_slice[:n_slices]))
@@ -1330,6 +1343,7 @@ def reduce_dimension_packed(
     reg.gauge("max_block_words").record_max(max_block_words)
     reg.gauge("use_kernels").set(float(use_kernels))
     reg.counter("n_device_calls").inc(n_device_calls)
+    reg.counter("n_kernel_lows").inc(n_kernel_lows)
     reg.gauge("n_shards").set(P)
     reg.counter("n_supersteps").inc(n_supersteps)
     reg.counter("n_exchange_rounds").inc(n_exchange_rounds)

@@ -2,7 +2,7 @@
 
 The inner loop of persistent-homology reduction is "add (mod 2) column i into
 column j" — on bit-packed uint32 words one VREG XOR covers 8x128x32 = 32,768
-matrix entries.  Two kernels:
+matrix entries.  Three kernels:
 
 * ``gf2_find_low`` — per-column index of the first set bit (the paper's
   ``low``): word-granular scan + count-trailing-zeros arithmetic, fully
@@ -15,7 +15,8 @@ matrix entries.  Two kernels:
   walk is a ``lax.while_loop`` inside the kernel.
 * ``gf2_parallel_xor`` — the *parallel phase* counterpart: XOR a column
   block against a gathered addend block (each batch column against the
-  committed pivot column owning its low) in one elementwise VREG pass.
+  committed pivot column owning its low) in one elementwise VREG pass,
+  returning each row's new low from the same pass.
 
 The host-side rank-compression vocabulary lives here too: the sorted
 unique ``universe`` of active cofacet keys maps key ``universe[i]`` to bit
@@ -320,16 +321,21 @@ def gf2_serial_reduce(blocks: jnp.ndarray, interpret: Optional[bool] = None):
     return red, lows[:, 0, :], reds[:, 0, 0]
 
 
-def _parallel_xor_kernel(cols_ref, addends_ref, out_ref):
-    out_ref[...] = cols_ref[...] ^ addends_ref[...]
+def _parallel_xor_kernel(cols_ref, addends_ref, out_ref, lows_ref):
+    xored = cols_ref[...] ^ addends_ref[...]
+    out_ref[...] = xored
+    lows_ref[...] = jnp.min(_bit_positions(xored), axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
 def gf2_parallel_xor(cols: jnp.ndarray, addends: jnp.ndarray,
                      block_c: int = 128,
-                     interpret: Optional[bool] = None) -> jnp.ndarray:
+                     interpret: Optional[bool] = None):
     """Parallel-phase GF(2) add: XOR a column block against a gathered
-    addend block.  cols, addends: (C, W) uint32; returns (C, W) uint32.
+    addend block.  cols, addends: (C, W) uint32; returns ``(xored, lows)``
+    — the (C, W) uint32 sum and its (C,) int32 first-set-bit rank per row
+    (NO_LOW for all-zero rows), the low found in the same pass so the
+    caller needs no find-low round trip after the add.
 
     The addend block is the host-side gather of committed pivot columns
     (one per batch column, zero rows where a column has no hit) packed into
@@ -341,14 +347,16 @@ def gf2_parallel_xor(cols: jnp.ndarray, addends: jnp.ndarray,
     cols = pad_to_multiple(cols, block_c, axis=0)
     addends = pad_to_multiple(addends, block_c, axis=0)
     cp = cols.shape[0]
-    out = pl.pallas_call(
+    out, lows = pl.pallas_call(
         _parallel_xor_kernel,
         grid=(cp // block_c,),
         in_specs=[pl.BlockSpec((block_c, w), lambda i: (i, 0)),
                   pl.BlockSpec((block_c, w), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_c, w), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((cp, w), jnp.uint32),
+        out_specs=[pl.BlockSpec((block_c, w), lambda i: (i, 0)),
+                   pl.BlockSpec((block_c, 1), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((cp, w), jnp.uint32),
+                   jax.ShapeDtypeStruct((cp, 1), jnp.int32)],
         interpret=interpret,
         name="gf2_parallel_xor",
     )(cols, addends)
-    return out[:c]
+    return out[:c], lows[:c, 0]

@@ -75,6 +75,9 @@ SCHEMA: Dict[str, MetricSpec] = {s.name: s for s in [
     _spec("n_device_calls", "counter", "calls",
           "gf2 kernel round trips (host array to device and back); "
           "0 on the host path"),
+    _spec("n_kernel_lows", "counter", "rows",
+          "rows whose low came back with the gf2 call that wrote them; "
+          "0 on the host path"),
     _spec("max_block_words", "gauge", "words",
           "widest packed block row (kernel path: as padded for the kernels)"),
     # -- distributed packed driver --

@@ -8,7 +8,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ref as kref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.gf2 import gf2_find_low, gf2_serial_reduce
+from repro.kernels.gf2 import (find_low_np, gf2_find_low, gf2_parallel_xor,
+                               gf2_serial_reduce)
 from repro.kernels.pairwise_dist import pairwise_sq_dists
 from repro.kernels import ops
 
@@ -103,6 +104,33 @@ def test_find_low_hypothesis(seed):
             * rng.integers(0, 2, size=(128, w), dtype=np.uint32))
     out = np.asarray(gf2_find_low(jnp.asarray(cols), interpret=True))
     np.testing.assert_array_equal(out, kref.gf2_find_low_ref(cols))
+
+
+# rows not a multiple of the 128-row block, engine widths of 128-1,024
+# words; the addends cancel some rows to zero and reach into others' lead
+@pytest.mark.parametrize("c,w", [(32, 128), (96, 384), (130, 640),
+                                 (200, 1024), (256, 256)])
+def test_gf2_parallel_xor_returns_lows_of_its_sum(c, w):
+    rng = np.random.default_rng(c + w)
+    cols = rng.integers(0, 2**32, size=(c, w), dtype=np.uint32)
+    cols[np.arange(w)[None, :] < rng.integers(0, w, size=c)[:, None]] = 0
+    addends = np.zeros_like(cols)
+    hit = rng.random(c) < 0.7
+    addends[hit] = rng.integers(0, 2**32, size=(hit.sum(), w),
+                                dtype=np.uint32)
+    addends[hit] &= rng.integers(0, 2**32, size=(hit.sum(), w),
+                                 dtype=np.uint32)
+    lead = rng.integers(0, w, size=c)
+    addends[np.arange(w)[None, :] < lead[:, None]] = 0
+    addends[::5] = cols[::5]                  # sums that are all zero
+    cols[::9] = 0
+    xored, lows = gf2_parallel_xor(jnp.asarray(cols), jnp.asarray(addends),
+                                   interpret=True)
+    xored, lows = np.asarray(xored), np.asarray(lows)
+    np.testing.assert_array_equal(xored, cols ^ addends)
+    np.testing.assert_array_equal(lows, find_low_np(xored))
+    assert lows.shape == (c,) and lows.dtype == np.int32
+    assert (lows == 2**31 - 1).any() and (lows != 2**31 - 1).any()
 
 
 @pytest.mark.parametrize("g,c,w", [(1, 8, 4), (2, 16, 8), (4, 32, 2),

@@ -15,7 +15,8 @@ from repro.core import build_filtration, compute_ph
 from repro.core.diagrams import assert_diagrams_equal
 from repro.core.h0 import compute_h0
 from repro.core.homology import make_h1_adapter, make_h2_adapter, h2_columns
-from repro.core.packed_reduce import reduce_dimension_packed
+from repro.core.packed_reduce import _PackedBatch, reduce_dimension_packed
+from repro.core.pairing import EMPTY_KEY
 from repro.core.reduction import (DimensionAdapter, PivotStore,
                                   merge_cancel, reduce_dimension)
 from repro.kernels.gf2 import (NO_LOW, bits_to_keys, find_low_np,
@@ -97,9 +98,11 @@ def test_gf2_parallel_xor_kernel(c, w):
     rng = np.random.default_rng(11)
     a = rng.integers(0, 2**32, size=(c, w), dtype=np.uint32)
     b = rng.integers(0, 2**32, size=(c, w), dtype=np.uint32)
-    out = np.asarray(gf2_parallel_xor(jnp.asarray(a), jnp.asarray(b),
-                                      interpret=True))
-    np.testing.assert_array_equal(out, a ^ b)
+    a[::3] = b[::3]                           # rows that cancel
+    out, lows = gf2_parallel_xor(jnp.asarray(a), jnp.asarray(b),
+                                 interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), a ^ b)
+    np.testing.assert_array_equal(np.asarray(lows), find_low_np(a ^ b))
 
 
 @settings(max_examples=15, deadline=None)
@@ -220,6 +223,78 @@ def test_packed_kernel_path_matches_host():
     h2k = reduce_dimension_packed(a2, cols2, use_kernels=True,
                                   batch_size=16)
     assert np.array_equal(h2h.diagram(), h2k.diagram())
+    for host_res, kern_res in ((host, kern), (h2h, h2k)):
+        assert host_res.stats["n_kernel_lows"] == 0
+        assert kern_res.stats["n_kernel_lows"] > 0
+
+
+def kernel_batch(seed, B=40, n_keys=300):
+    """A kernel-path batch whose rows share leading keys (serial
+    collisions), with empty rows and two rows evicted to scalar form."""
+    rng = np.random.default_rng(seed)
+    universe = np.unique(rng.integers(0, 10**9, size=n_keys)).astype(np.int64)
+    heads = rng.choice(universe[:40], size=8, replace=False)
+    cob = np.full((B, 24), EMPTY_KEY, dtype=np.int64)
+    for i in range(B):
+        if i % 11 == 10:
+            continue                          # an empty row
+        body = rng.choice(universe, size=int(rng.integers(1, 23)),
+                          replace=False)
+        row = np.unique(np.append(body, heads[i % 8]))[:24]
+        cob[i, :len(row)] = row
+    blk = _PackedBatch(cob, [universe], use_kernels=True)
+    for c in (3, 17):
+        blk.evict(c)
+    return rng, universe, blk
+
+
+def unpacked_lows(blk):
+    """The oracle: each row's least key, -1 when the row is empty."""
+    return np.array([int(k.min()) if k.size else -1
+                     for k in blk.unpack(np.arange(blk.B))])
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_kernel_path_lows_match_host_refresh(seed):
+    """Lows the gf2 calls return equal a host find-low of the same rows,
+    after the parallel XOR and after the serial pre-pass; evicted rows'
+    lows survive both."""
+    rng, universe, blk = kernel_batch(seed)
+    np.testing.assert_array_equal(blk.lows, unpacked_lows(blk))
+    B = blk.B
+    hit = sorted(set(rng.choice(B, size=24, replace=False).tolist()) | {3})
+    addends = [None] * B
+    for i in hit:
+        addends[i] = np.sort(rng.choice(universe, size=int(
+            rng.integers(1, 30)), replace=False))
+    # one packed row cancels to zero
+    cancel = next(i for i in hit if i not in blk.scalar and blk.lows[i] >= 0)
+    addends[cancel] = blk.unpack(np.array([cancel]))[0]
+    n_packed = len([i for i in hit if i not in blk.scalar])
+    blk.xor_addends(hit, addends)
+    assert blk.n_kernel_lows == n_packed
+    assert blk.n_device_calls == 1
+    assert blk.lows[cancel] == -1
+    np.testing.assert_array_equal(blk.lows, unpacked_lows(blk))
+
+    # a copy of an earlier packed row reduces to zero in the pre-pass: its
+    # kernel low then lands in the V-words and must read as empty
+    live = [i for i in range(B) if blk.lows[i] >= 0 and i not in blk.scalar]
+    src, dup = live[0], live[-1]
+    blk.block[dup] = blk.block[src]
+    blk.lows[dup] = blk.lows[src]
+    scalar_lows = {c: int(blk.lows[c]) for c in blk.scalar}
+    gens = [dict() for _ in range(B)]
+    changed = {}
+    n_red = blk._serial_kernel_prepass(gens, list(range(B)), changed)
+    assert n_red > 0 and changed
+    assert blk.n_kernel_lows == n_packed + len(changed)
+    assert blk.n_device_calls == 2
+    assert dup in changed and blk.lows[dup] == -1
+    np.testing.assert_array_equal(blk.lows, unpacked_lows(blk))
+    assert {c: int(blk.lows[c]) for c in blk.scalar} == scalar_lows
+    live = [i for i in range(B) if blk.lows[i] >= 0 and i not in blk.scalar]
+    assert len({int(blk.lows[i]) for i in live}) == len(live)
 
 
 def test_packed_h2_full_pipeline_vs_oracle():
