@@ -1,25 +1,12 @@
-"""Point clouds of the benchmark's deployments, by their published definitions.
+"""Isometric copies of point clouds, shared by every dataset.
 
-Kept with the benchmark so that its inputs cannot change under a later PR:
-
-``o3`` (Dory, arXiv 2103.05608, Table 1; Ripser's benchmark, arXiv
-1908.02518): random orthogonal 3x3 matrices, Haar-distributed on O(3), as
-points of R^9.
+A dataset's own points come from ``bench/datasets/<name>.py``; a run then
+moves them by an isometry drawn from ``--seed``, so every seed asks the same
+work in other coordinates.
 """
 from __future__ import annotations
 
 import numpy as np
-
-
-def haar_o3(rng: np.random.Generator, n: int) -> np.ndarray:
-    """``n`` Haar-random orthogonal 3x3 matrices, shape ``(n, 3, 3)``."""
-    q, r = np.linalg.qr(rng.normal(size=(n, 3, 3)))
-    return q * np.sign(np.diagonal(r, axis1=1, axis2=2))[:, None, :]
-
-
-def o3(rng: np.random.Generator, n: int) -> np.ndarray:
-    """``n`` o3 points: Haar-random orthogonal 3x3 matrices as rows of R^9."""
-    return haar_o3(rng, n).reshape(n, 9)
 
 
 def random_isometry(rng: np.random.Generator, d: int) -> np.ndarray:
@@ -48,4 +35,3 @@ def isometric_copy(base: np.ndarray, rng: np.random.Generator) -> np.ndarray:
     reduction meets equal-diameter columns, and with it the work.)
     """
     return rotate(base, random_isometry(rng, base.shape[1]))
-

@@ -23,14 +23,14 @@ import numpy as np
 if __name__ == "__main__":
     sys.path[0] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-from bench import reference, spec    # noqa: E402
-from bench.closed_loop import ClosedLoop    # noqa: E402
+from bench import spec    # noqa: E402
 
 
-def control_driver(cell, seed: int, dtype=np.float32) -> ClosedLoop:
-    def solve(q):
-        return reference.diagrams(q.points, q.tau, q.maxdim, dtype), {}
-    return ClosedLoop(cell, seed, solve=solve, guard=False)
+def control_driver(cell, seed: int, dtype=np.float32):
+    """The cell's loop, driven with the reference in ``dtype`` answering in
+    the program's place (``control`` of ``bench/loops/<loop>.py``)."""
+    return spec.load_part(cell.root, "loops", cell.mix["loop"]).control(
+        cell, seed, dtype)
 
 
 def main(argv=None) -> int:

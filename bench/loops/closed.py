@@ -42,7 +42,7 @@ class ClosedLoop:
     def __init__(self, cell, seed: int, solve: Optional[Solve] = None,
                  guard: bool = True):
         self.pool, self.warm = traffic.closed_loop(cell.config, cell.mix,
-                                                   seed)
+                                                   seed, cell.root)
         self.maxdim = cell.mix["maxdim"]
         self.solve = solve or program_solve
         self.guard = guard
@@ -119,3 +119,14 @@ class ClosedLoop:
                     q.points, q.tau, q.maxdim)
             off += compare.bars_off(call["diagrams"], refs[call["query"]])
         return {"bars_off": (off, 0)}
+
+
+def driver(cell, seed: int) -> ClosedLoop:
+    return ClosedLoop(cell, seed)
+
+
+def control(cell, seed: int, dtype) -> ClosedLoop:
+    """The reference, computed in ``dtype``, in ``compute_ph``'s place."""
+    def solve(q):
+        return reference.diagrams(q.points, q.tau, q.maxdim, dtype), {}
+    return ClosedLoop(cell, seed, solve=solve, guard=False)

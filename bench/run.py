@@ -75,11 +75,9 @@ class Run:
 
 
 def make_driver(cell, seed: int):
-    loop = cell.mix["loop"]
-    if loop == "closed":
-        from bench.closed_loop import ClosedLoop
-        return ClosedLoop(cell, seed)
-    raise ValueError(f"unknown loop {loop!r}")
+    """The driver of the cell's loop, from ``bench/loops/<loop>.py``."""
+    return spec.load_part(cell.root, "loops", cell.mix["loop"]).driver(
+        cell, seed)
 
 
 def devices_or_exit(chips: int, require_tpu: bool):

@@ -2,7 +2,12 @@
 names the cell; its configuration file, its traffic mix
 (``bench/traffic/<traffic>.json``) and one reader per per-layer metric
 (``bench/metrics/<metric>.py``, a function ``read(run)``) are loaded from
-their own files, so a later PR adds a cell, mix or metric by adding files.
+their own files.  So are the mix's loop (``"loop": "<x>"`` in the mix:
+``bench/loops/<x>.py``, functions ``driver(cell, seed)`` and
+``control(cell, seed, dtype)``, the latter for ``bench/control.py``) and the
+configuration's dataset (``"dataset": "<y>"``: ``bench/datasets/<y>.py``, a
+function ``points(config, rng, n)``).  A later PR adds a cell, mix, metric,
+loop or dataset by adding files, and edits none of these modules.
 """
 from __future__ import annotations
 
@@ -10,6 +15,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+from types import ModuleType
 from typing import Callable, Dict, List
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -36,12 +42,27 @@ def _reports(metric: Dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def load_reader(path: str) -> Callable:
-    mod_name = "bench_metric_" + os.path.basename(path)[:-3].replace(".", "_")
+def load_file(path: str, prefix: str) -> ModuleType:
+    """The Python file ``path``, imported as a module of its own."""
+    mod_name = prefix + os.path.basename(path)[:-3].replace(".", "_")
     spec = importlib.util.spec_from_file_location(mod_name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_reader(path: str) -> Callable:
+    return load_file(path, "bench_metric_").read
+
+
+def load_part(root: str, kind: str, name: str) -> ModuleType:
+    """``bench/<kind>/<name>.py`` under ``root``: a loop (``kind``
+    ``loops``) or a dataset (``datasets``)."""
+    path = os.path.join(root, "bench", kind, name + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"unknown {kind[:-1]} {name!r}: no "
+                         f"{os.path.relpath(path, root)}")
+    return load_file(path, f"bench_{kind}_")
 
 
 def load(workload: str, root: str = ROOT) -> Cell:

@@ -160,3 +160,60 @@ def test_float32_control_is_not_correct(root):
                   driver=control.control_driver(cell, seed))
     assert not out["correct"]
     assert out["checks"]["bars_off"]["value"] > 0
+
+
+NEW_DATASET = '''"""Points on a circle of radius 1 in R^2."""
+import numpy as np
+
+
+def points(config, rng, n):
+    a = rng.uniform(0.0, 2.0 * np.pi, n)
+    return np.stack([np.cos(a), np.sin(a)], axis=1)
+'''
+
+NEW_LOOP = '''"""One call in the window, however long it is."""
+import time
+
+from bench.loops.closed import ClosedLoop
+
+
+class Once(ClosedLoop):
+    def window(self, seconds):
+        t0 = time.perf_counter()
+        diagrams, stats = self.solve(self.pool[0])
+        self.calls.append({"t0": t0, "t1": time.perf_counter(), "query": 0,
+                           "d": 2, "diagrams": diagrams, "stats": stats})
+
+
+def driver(cell, seed):
+    return Once(cell, seed)
+'''
+
+
+def test_a_new_dataset_and_loop_are_one_file_each(root, device_path, capsys):
+    """A cell whose configuration names a new dataset and whose mix names a
+    new loop runs with nothing edited: each is one new file under bench/."""
+    b = os.path.join(root, "bench")
+    with open(os.path.join(b, "datasets", "circle.py"), "w") as f:
+        f.write(NEW_DATASET)
+    with open(os.path.join(b, "loops", "once.py"), "w") as f:
+        f.write(NEW_LOOP)
+    _write(os.path.join(b, "configs", "circle.json"),
+           {"dataset": "circle", "n": 40, "base_seeds": [3]})
+    _write(os.path.join(b, "traffic", "once.json"),
+           {"loop": "once", "tau_max": 0.4, "maxdim": 1})
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "circle", "source": "test",
+                             "file": "bench/configs/circle.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "circle.once", "config": "circle",
+                               "traffic": "once", "chips": 1, "why": "test"})
+    bench["end_to_end"][0]["workloads"].append("circle.once")
+    _write(path, bench)
+    assert run.main(["--workload", "circle.once", "--seed", "5",
+                     "--seconds", "1"], root=root, require_tpu=False) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["correct"] and out["attempted"] == 1
+    assert set(out["metrics"]) == {"ph_s", "setup_s"}

@@ -2,14 +2,18 @@
 
 A traffic mix is a data file ``bench/traffic/<name>.json``; a configuration
 is ``bench/configs/<name>.json``.  This module turns the two and ``--seed``
-into every input of a run, before the window opens.
+into every input of a run, before the window opens.  The mix's ``"loop"``
+names the driver that sends them (``bench/loops/<loop>.py``), and the
+configuration's ``"dataset"`` the generator of its points
+(``bench/datasets/<dataset>.py``); both are found by file
+(``bench/spec.py``).
 
-One loop exists, ``closed``: one caller, calls back to back over a pool of
-clouds.  The configuration names fixed samples of its dataset
-(``base_seeds``); the pool holds one isometric copy of each, in the
-configuration's order and in coordinates drawn from ``--seed``.  Every run
-thus asks the same work of the system on other inputs, and the check still
-covers several complexes.
+The configuration fixes the work and ``--seed`` only the coordinates:
+``closed_loop``'s pool holds one isometric copy of each of the
+configuration's fixed samples (``base_seeds``), in the configuration's order
+and in coordinates drawn from ``--seed``.  Every run thus asks the same work
+of the system on other inputs, and the check still covers several
+complexes.
 """
 from __future__ import annotations
 
@@ -18,7 +22,7 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
-from bench import clouds
+from bench import clouds, spec
 
 
 @dataclasses.dataclass
@@ -34,18 +38,19 @@ def rng_of(seed: int) -> np.random.Generator:
     return np.random.default_rng(int(seed) % (1 << 64))
 
 
-def make_cloud(config: Dict, rng: np.random.Generator, n: int) -> np.ndarray:
-    if config["dataset"] == "o3":
-        return clouds.o3(rng, n)
-    raise ValueError(f"unknown dataset {config['dataset']!r}")
+def make_cloud(config: Dict, rng: np.random.Generator, n: int,
+               root: str = spec.ROOT) -> np.ndarray:
+    """``n`` points of the configuration's dataset."""
+    return spec.load_part(root, "datasets", config["dataset"]).points(
+        config, rng, n)
 
 
-def closed_loop(config: Dict, mix: Dict, seed: int
+def closed_loop(config: Dict, mix: Dict, seed: int, root: str = spec.ROOT
                 ) -> Tuple[List[Query], List[Query]]:
     """``(pool, warmup)``: the window's calls in the order they cycle, and
     one more call on each base sample, in coordinates of its own, to warm
     up every shape the pool asks for."""
-    bases = [make_cloud(config, rng_of(s), config["n"])
+    bases = [make_cloud(config, rng_of(s), config["n"], root)
              for s in config["base_seeds"]]
     rng = rng_of(seed)
 
