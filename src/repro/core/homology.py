@@ -9,7 +9,7 @@ trivial pairs detected on the fly throughout.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -134,6 +134,7 @@ def compute_ph(
     mode: str = "explicit",
     sparse: Optional[bool] = None,
     filtration: Optional[Filtration] = None,
+    coo: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray, int]] = None,
     engine: str = "single",
     batch_size: int = 128,
     backend: str = "dense",
@@ -150,6 +151,14 @@ def compute_ph(
 
     mode: "explicit" stores R^⊥ (paper Alg. 1 spirit), "implicit" stores only
     V^⊥ (paper Alg. 2 / fast implicit column spirit).
+    coo: a sparse distance map ``(rows, cols, vals, n)``: COO triplets (a
+    Hi-C map through ``contacts_to_distances``, say) and the bin count
+    ``n``, which is required: inferred from the largest id, bins past the
+    last contact would vanish with their infinite H0 bars.  The filtration
+    is built by ``repro.scale.build_filtration_coo`` inside this call's
+    ``ph/filtration`` stopwatch (``t_filtration``), with the counters
+    ``coo_entries``, ``coo_pairs`` and ``coo_edges``; missing pairs and
+    non-finite values are no edge.  ``backend`` does not apply.
     sparse: neighborhoods (Dory) vs dense order matrix (DoryNS); default picks
     NS for small n where the O(n^2) table is cheap, and always picks the
     order-free sparse path for streamed filtrations (no dense order matrix).
@@ -199,8 +208,17 @@ def compute_ph(
     (``predicted_account_bytes`` vs the ``observed_peak_*_bytes``
     high-water marks).
     """
+    if coo is not None:
+        if points is not None or dists is not None or filtration is not None:
+            raise ValueError("coo is an input of its own: pass no points, "
+                             "dists or filtration with it")
+        if len(coo) != 4:
+            raise ValueError("coo is (rows, cols, vals, n): the bin count n "
+                             "is required, since bins past the last contact "
+                             "would otherwise vanish")
     if mesh is not None and engine != "packed" \
-            and (filtration is not None or backend != "tiled"):
+            and (filtration is not None or coo is not None
+                 or backend != "tiled"):
         raise ValueError("mesh sharding requires backend='tiled' and no "
                          "prebuilt filtration (or engine='packed', which "
                          "distributes the reduction for any backend)")
@@ -219,6 +237,14 @@ def compute_ph(
         with stopwatch("ph/filtration") as sw_filt:
             if filtration is not None:
                 filt = filtration
+            elif coo is not None:
+                from ..scale import build_filtration_coo
+
+                rows, cols, vals, n_bins = coo
+                filt, coo_counts = build_filtration_coo(
+                    rows, cols, vals, n=n_bins, tau_max=tau_max,
+                    return_stats=True)
+                reg.update_from(coo_counts)
             elif backend == "tiled":
                 from ..scale import (build_filtration_sharded,
                                      build_filtration_tiled,

@@ -121,6 +121,12 @@ SCHEMA: Dict[str, MetricSpec] = {s.name: s for s in [
           "filtration result arrays: the (3n + 12 n_e) * 4 account realized"),
     _spec("tau_max_estimated", "gauge", "", "budget-derived tau_max"),
     _spec("sanitize_checks", "counter", "checks", "GF(2) sanitizer checks run"),
+    _spec("coo_entries", "counter", "entries",
+          "COO input: triplets handed in, diagonal and duplicates included"),
+    _spec("coo_pairs", "counter", "pairs",
+          "COO input: unique off-diagonal pairs after symmetrizing"),
+    _spec("coo_edges", "counter", "edges",
+          "COO input: pairs kept as edges (finite, at most tau_max)"),
     _spec("harvest_pallas", "gauge", "flag",
           "tiled harvest backend: 1 = Pallas distance kernel, 0 = numpy"),
     _spec("per_device_peak_bytes", "gauge", "bytes",

@@ -7,16 +7,18 @@ absent from the COO set are treated as infinitely far (no edge), exactly like
 a dense matrix whose missing entries exceed ``tau_max``, so
 ``build_filtration_coo`` is bit-identical to a dense ``dists=`` call on the
 materialized matrix (asserted in tests) while never allocating ``O(n^2)``.
-Workload walk-through and field reference: ``docs/architecture.md`` and
-``docs/api.md``.
+``compute_ph(coo=(rows, cols, vals, n))`` runs this build inside its own
+filtration stopwatch, under the ``coo/*`` spans.  Workload walk-through and
+field reference: ``docs/architecture.md`` and ``docs/api.md``.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple, Union
 
 import numpy as np
 
 from ..core.filtration import Filtration, filtration_from_edges
+from ..obs.trace import span
 
 
 def coo_symmetrize(
@@ -64,18 +66,38 @@ def build_filtration_coo(
     n: Optional[int] = None,
     tau_max: float = np.inf,
     with_dense_order: bool = False,
-) -> Filtration:
+    return_stats: bool = False,
+) -> Union[Filtration, Tuple[Filtration, Dict[str, float]]]:
     """Sparse-input :class:`Filtration`: COO distances in, Dory structure out.
 
     Memory is ``O(nnz + n)`` throughout; the dense order matrix stays lazy
     (``with_dense_order=False``) so the sparse Dory path runs order-free.
     Non-finite values (the ``contacts_to_distances`` "no information" inf)
     never become edges, even at ``tau_max=inf``.
+
+    Pass the bin count ``n``: left ``None``, ``coo_symmetrize`` takes it
+    from the largest id, so bins past the last contact of a map (the end of
+    a chromosome) vanish, and with them their infinite H0 bars.
+    ``compute_ph(coo=...)`` requires it.  With ``return_stats`` the exact
+    counts come back too: ``coo_entries`` (triplets in), ``coo_pairs``
+    (unique off-diagonal pairs) and ``coo_edges`` (pairs kept at
+    ``tau_max``).
     """
-    n, iu, ju, vals = coo_symmetrize(rows, cols, vals, n=n)
-    keep = (vals <= tau_max) & np.isfinite(vals)
-    return filtration_from_edges(n, iu[keep], ju[keep], vals[keep], tau_max,
-                                 with_dense_order=with_dense_order)
+    with span("coo/build"):
+        with span("coo/symmetrize"):
+            n, iu, ju, lens = coo_symmetrize(rows, cols, vals, n=n)
+        n_pairs = iu.size
+        with span("coo/filter"):
+            keep = (lens <= tau_max) & np.isfinite(lens)
+            iu, ju, lens = iu[keep], ju[keep], lens[keep]
+        with span("coo/edges"):
+            filt = filtration_from_edges(n, iu, ju, lens, tau_max,
+                                         with_dense_order=with_dense_order)
+    if not return_stats:
+        return filt
+    return filt, {"coo_entries": float(np.size(rows)),
+                  "coo_pairs": float(n_pairs),
+                  "coo_edges": float(filt.n_e)}
 
 
 def contacts_to_distances(
@@ -86,11 +108,18 @@ def contacts_to_distances(
     """Hi-C contact counts -> distances via the power law ``d = s * c^alpha``.
 
     The standard polymer-physics conversion (Lieberman-Aiden et al.):
-    frequently contacting loci are spatially close.  Zero / negative counts
-    map to ``inf`` (no information, no edge).
+    frequently contacting loci are spatially close.  Zero, negative and
+    non-finite counts (a balanced map's NaN for a masked bin) map to
+    ``inf`` (no information, no edge).  At ``alpha = -1`` the
+    distance is the correctly rounded quotient ``scale / c``, so distances
+    order pixels exactly as their contacts do, reversed; ``pow`` may be a
+    unit in the last place off and reorder near-equal contacts.
     """
     counts = np.asarray(counts, dtype=np.float64)
     out = np.full(counts.shape, np.inf)
-    pos = counts > 0
-    out[pos] = scale * np.power(counts[pos], alpha)
+    pos = np.isfinite(counts) & (counts > 0)
+    if alpha == -1.0:
+        out[pos] = scale / counts[pos]
+    else:
+        out[pos] = scale * np.power(counts[pos], alpha)
     return out

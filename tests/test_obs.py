@@ -116,6 +116,7 @@ LEAF_SPANS = {
     "gf2/xor", "gf2/serial",
     "harvest/fetch", "harvest/refine", "harvest/build",
     "ph/adapter", "ph/h2_columns",
+    "coo/symmetrize", "coo/filter", "coo/edges",
 }
 
 
@@ -148,21 +149,37 @@ def device_call(tracer=None):
                       batch_size=16, trace=tracer)
 
 
+def coo_call(tracer=None):
+    """The COO entry on the same cloud's distances, as a contact map would
+    give them: every pair once, in both orders, beside one diagonal entry."""
+    pts = cloud(seed=13, n=16)
+    i, j = np.triu_indices(16, k=1)
+    d = np.linalg.norm(pts[i] - pts[j], axis=1)
+    rows = np.concatenate([i, j, [3]])
+    cols = np.concatenate([j, i, [3]])
+    vals = np.concatenate([d, d, [0.0]])
+    return compute_ph(coo=(rows, cols, vals, 16), maxdim=2, engine="packed",
+                      batch_size=16, trace=tracer)
+
+
 def test_bridge_annotates_every_span_once(device_path, annotations):
     tr = Tracer(bridge=True)
     device_call(tr)
+    coo_call(tr)
     tr.assert_balanced()
     recorded = collections.Counter(s.name for s in tr.spans)
     assert collections.Counter(annotations) == recorded
     # stopwatches, the packed engine's local timeline, and every leaf
     assert {"ph/filtration", "ph/h0", "ph/h1", "ph/h2"} <= set(recorded)
     assert {"reduce/fused", "reduce/slice", "reduce/sweep"} <= set(recorded)
+    assert "coo/build" in recorded
     assert LEAF_SPANS <= set(recorded)
 
 
 def test_no_annotation_without_bridge(device_path, annotations):
     tr = Tracer()
     device_call(tr)
+    coo_call(tr)
     assert LEAF_SPANS <= {s.name for s in tr.spans}
     assert annotations == []
 
