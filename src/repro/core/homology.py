@@ -157,7 +157,8 @@ def compute_ph(
     last contact would vanish with their infinite H0 bars.  The filtration
     is built by ``repro.scale.build_filtration_coo`` inside this call's
     ``ph/filtration`` stopwatch (``t_filtration``), with the counters
-    ``coo_entries``, ``coo_pairs`` and ``coo_edges``; missing pairs and
+    ``coo_entries``, ``coo_candidates``, ``coo_pairs`` and ``coo_edges``;
+    missing pairs and
     non-finite values are no edge.  ``backend`` does not apply.
     sparse: neighborhoods (Dory) vs dense order matrix (DoryNS); default picks
     NS for small n where the O(n^2) table is cheap, and always picks the

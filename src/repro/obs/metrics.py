@@ -123,8 +123,10 @@ SCHEMA: Dict[str, MetricSpec] = {s.name: s for s in [
     _spec("sanitize_checks", "counter", "checks", "GF(2) sanitizer checks run"),
     _spec("coo_entries", "counter", "entries",
           "COO input: triplets handed in, diagonal and duplicates included"),
+    _spec("coo_candidates", "counter", "entries",
+          "COO input: triplets at most tau_max, the input of the dedup sort"),
     _spec("coo_pairs", "counter", "pairs",
-          "COO input: unique off-diagonal pairs after symmetrizing"),
+          "COO input: unique off-diagonal pairs of all triplets"),
     _spec("coo_edges", "counter", "edges",
           "COO input: pairs kept as edges (finite, at most tau_max)"),
     _spec("harvest_pallas", "gauge", "flag",

@@ -8,12 +8,14 @@ pixel table is made untidy the ways real ones are: duplicate pixels,
 at the end with no contact at all.  ``compute_ph(coo=...)`` has to give the
 diagrams of the dense ``dists=`` call on the materialised matrix and of
 ``build_filtration_coo`` then ``compute_ph(filtration=...)``, for every
-engine, and count the map's entries, pairs and edges exactly.
+engine, and count the map's entries, candidates, pairs and edges
+exactly.
 """
 import numpy as np
 import pytest
 
 from repro.core import compute_ph
+from repro.core.filtration import filtration_from_edges
 from repro.obs.trace import Tracer
 from repro.scale import build_filtration_coo, contacts_to_distances
 
@@ -123,8 +125,74 @@ def test_coo_counters_are_exact(seed):
     edges = sum(1 for v in best.values() if np.isfinite(v) and v <= TAU)
     res = compute_ph(coo=(rows, cols, d, n), tau_max=TAU, maxdim=1)
     assert res.stats["coo_entries"] == rows.size
+    assert res.stats["coo_candidates"] == sum(1 for v in d if v <= TAU)
+    assert res.stats["coo_candidates"] < rows.size
     assert res.stats["coo_pairs"] == len(best)
     assert res.stats["coo_edges"] == edges == res.stats["n_e"]
+
+
+def untidy_triplets(seed, n=30, size=400, pool=60):
+    """Triplets over ``pool`` pairs in both orientations, the diagonal
+    among them, with values on a grid of eighths (so ties and values equal
+    to a τ of the grid) and NaN, ``+inf`` and ``-inf``."""
+    rng = np.random.default_rng(seed)
+    pairs = rng.integers(0, n, size=(pool, 2))
+    pick = pairs[rng.integers(0, pool, size)]
+    flip = rng.random(size) < 0.5
+    rows = np.where(flip, pick[:, 1], pick[:, 0])
+    cols = np.where(flip, pick[:, 0], pick[:, 1])
+    vals = rng.integers(-2, 9, size) * 0.125
+    special = rng.choice(size, 30, replace=False)
+    vals[special] = np.resize([np.nan, np.inf, -np.inf], 30)
+    return rows, cols, vals, n
+
+
+def reference_filtration(rows, cols, vals, n, tau):
+    """Each off-diagonal pair at its smallest value, NaN only where every
+    entry is NaN; the pairs whose smallest value is finite and at most
+    ``tau`` become edges.  Also the count of unique pairs."""
+    best = {}
+    for a, b, v in zip(rows.tolist(), cols.tolist(), vals.tolist()):
+        if a == b:
+            continue
+        key = (min(a, b), max(a, b))
+        old = best.get(key, np.nan)
+        best[key] = v if np.isnan(old) or v < old else old
+    kept = sorted((k, v) for k, v in best.items()
+                  if np.isfinite(v) and v <= tau)
+    iu = np.array([k[0] for k, _ in kept], dtype=np.int64)
+    ju = np.array([k[1] for k, _ in kept], dtype=np.int64)
+    lens = np.array([v for _, v in kept], dtype=np.float64)
+    return filtration_from_edges(n, iu, ju, lens, tau), len(best)
+
+
+@pytest.mark.parametrize("tau", [0.25, 0.5, np.inf])
+@pytest.mark.parametrize("seed", [7, 8, 9])
+def test_masking_by_tau_first_keeps_the_edges_of_the_full_dedup(seed, tau):
+    rows, cols, vals, n = untidy_triplets(seed)
+    assert np.any(vals == tau) or np.isinf(tau)
+    filt, st = build_filtration_coo(rows, cols, vals, n=n, tau_max=tau,
+                                    return_stats=True)
+    ref, n_pairs = reference_filtration(rows, cols, vals, n, tau)
+    assert filt.n == ref.n and filt.n_e == ref.n_e > 0
+    for field in ("edges", "edge_len", "nbr_vtx", "nbr_edge_ord"):
+        assert np.array_equal(getattr(filt, field), getattr(ref, field)), \
+            field
+    assert st["coo_pairs"] == n_pairs
+    assert st["coo_candidates"] == sum(1 for v in vals if v <= tau)
+    assert st["coo_edges"] == ref.n_e
+
+
+def test_inferred_bin_count_counts_an_id_seen_only_beyond_tau():
+    rows, cols, vals, n = untidy_triplets(10)
+    rows, cols = rows % (n - 1), cols % (n - 1)     # id n - 1 unused
+    rows = np.append(rows, n - 1)
+    cols = np.append(cols, 0)
+    vals = np.append(vals, 10.0)                    # far beyond tau
+    filt, st = build_filtration_coo(rows, cols, vals, tau_max=0.5,
+                                    return_stats=True)
+    assert filt.n == n and filt.degree[n - 1] == 0
+    assert st["coo_candidates"] < rows.size
 
 
 def test_coo_build_is_inside_the_filtration_stopwatch():
@@ -136,9 +204,12 @@ def test_coo_build_is_inside_the_filtration_stopwatch():
     by_name = {s.name: s for s in tr.spans}
     filt, build = by_name["ph/filtration"], by_name["coo/build"]
     assert filt.t0 <= build.t0 and build.t1 <= filt.t1
-    for leaf in ("coo/symmetrize", "coo/filter", "coo/edges"):
-        s = by_name[leaf]
-        assert build.t0 <= s.t0 and s.t1 <= build.t1, leaf
+    leaves = [by_name[leaf]
+              for leaf in ("coo/filter", "coo/symmetrize", "coo/edges")]
+    for s in leaves:
+        assert build.t0 <= s.t0 and s.t1 <= build.t1, s.name
+    for a, b in zip(leaves, leaves[1:]):
+        assert a.t1 <= b.t0, (a.name, b.name)
     assert res.stats["t_filtration"] >= build.t1 - build.t0
 
 
