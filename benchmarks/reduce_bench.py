@@ -157,7 +157,7 @@ def run_distributed(record: dict, dists, shards: list, batch_size: int,
 
     from repro.core import compute_ph
     from repro.core.diagrams import assert_diagrams_equal
-    from repro.launch.mesh import make_data_mesh
+    from repro.scale import make_data_mesh
 
     reference = record.pop("_reference_diagrams")
     n_dev = jax.device_count()

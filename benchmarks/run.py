@@ -1,5 +1,4 @@
-"""Benchmark orchestrator: one section per paper table/figure + the
-roofline report.
+"""Benchmark orchestrator: one section per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--scale 1.0] [--skip table3]
 """
@@ -19,7 +18,7 @@ def main() -> None:
                     help="section name to skip (repeatable)")
     args = ap.parse_args()
 
-    from . import (fig21_hic, roofline, table1_datasets, table2_phases,
+    from . import (fig21_hic, table1_datasets, table2_phases,
                    table3_vs_baseline, table4_variants)
 
     sections = [
@@ -33,8 +32,6 @@ def main() -> None:
          lambda: table4_variants.main(args.scale)),
         ("fig21_hic (paper Fig. 21)",
          lambda: fig21_hic.main(args.scale)),
-        ("roofline (EXPERIMENTS.md §Roofline, from dry-run artifacts)",
-         roofline.main),
     ]
 
     failures = 0

@@ -50,7 +50,7 @@ def run(n: int, budget_mb: float, tile: int, maxdim: int, seed: int,
         import jax
         mesh = None
         if len(jax.devices()) >= devices:
-            from repro.launch.mesh import make_data_mesh
+            from repro.scale import make_data_mesh
             mesh = make_data_mesh(devices)
             shard_mode = "mesh"
         else:

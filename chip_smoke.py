@@ -191,7 +191,7 @@ def phase_mesh(compiles: CompileCounter, n: int = O3_N,
     import jax
 
     from repro.core.homology import compute_ph
-    from repro.launch.mesh import make_data_mesh
+    from repro.scale import make_data_mesh
 
     points = o3_cloud(n)
     mesh = make_data_mesh(4)

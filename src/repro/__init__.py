@@ -1,2 +1,2 @@
-"""repro: Dory-JAX — persistent homology at scale + multi-pod LM framework."""
+"""repro: Dory-JAX — persistent homology at scale."""
 __version__ = "1.0.0"

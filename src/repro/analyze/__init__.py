@@ -34,7 +34,6 @@ it ships, in three layers:
 non-zero on any unjustified finding; CI runs it on every push.  See
 ``docs/analysis.md`` for the field guide.
 """
-from . import lint
 from .invariants import (SanitizeViolation, Sanitizer, active_sanitizer,
                          sanitizing)
 
@@ -43,5 +42,4 @@ __all__ = [
     "Sanitizer",
     "active_sanitizer",
     "sanitizing",
-    "lint",
 ]

@@ -17,16 +17,15 @@ devices attached:
   ordered :class:`CollectiveOp` list plus :class:`Violation` records for
   divergent ``cond`` branches and collectives under ``while``.
 * :func:`collective_schedule_from_hlo` does the same walk over compiled
-  HLO text, reusing the ``launch/hlo.py`` parser — the post-XLA
+  HLO text, parsed by :mod:`repro.analyze.hlo` — the post-XLA
   cross-check (DCE or rewrites can change the schedule the jaxpr
   promised).
 * :func:`check_repo` traces the registered ``shard_map`` round functions
-  of ``scale/shard.py``, ``core/packed_reduce.py`` and
-  ``dist/compression.py``, verifies their axis names against the mesh
-  they run on, pins each traced schedule against the registry, and
-  exercises replica-consistency of the pivot-exchange wire
-  (``stack_wire_payloads`` round-trip + Elias–Fano delta codec) on
-  deliberately uneven per-shard payloads.
+  of ``scale/shard.py`` and ``core/packed_reduce.py``, verifies their
+  axis names against the mesh they run on, pins each traced schedule
+  against the registry, and exercises replica-consistency of the
+  pivot-exchange wire (``stack_wire_payloads`` round-trip + Elias–Fano
+  delta codec) on deliberately uneven per-shard payloads.
 
 Heavy imports (``jax``, the repro modules under test) happen inside
 functions so that importing this module stays cheap.
@@ -34,7 +33,7 @@ functions so that importing this module stays cheap.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import Any, Callable, List, Mapping, Optional, Sequence, Tuple
 
 __all__ = [
     "CollectiveOp",
@@ -221,38 +220,24 @@ def verify_axes(schedule: Schedule,
 
 
 # ---------------------------------------------------------------------------
-# HLO-level cross-check (post-XLA), reusing the launch/hlo.py parser.
+# HLO-level cross-check (post-XLA), over the analyze/hlo.py parser.
 # ---------------------------------------------------------------------------
 
-def collective_schedule_from_hlo(hlo_text: str, where: str = "<hlo>",
-                                 pod_size: int = 256) -> Schedule:
+def collective_schedule_from_hlo(hlo_text: str,
+                                 where: str = "<hlo>") -> Schedule:
     """Extract the collective schedule from compiled HLO text.
 
     Walks the entry computation in program order, inlining called and
-    fusion-called computations and while bodies, reusing the
-    ``launch/hlo.py`` parser.  A collective reached through a while loop
+    fusion-called computations and while bodies (parsed by
+    :mod:`repro.analyze.hlo`).  A collective reached through a while loop
     whose trip count the parser cannot prove is flagged
     ``while-collective`` — the same deadlock class as the jaxpr walker,
     but after XLA had its say (DCE and rewrites can change the schedule
     the jaxpr promised).
     """
-    import re
+    from .hlo import COLLECTIVES, group_size, parse_module
 
-    from ..launch.hlo import (COLLECTIVES, _group_info, _parse_computation,
-                              _split_computations)
-
-    raw = _split_computations(hlo_text)
-    parsed = {name: _parse_computation(name, lines, pod_size)
-              for name, lines in raw.items()}
-    entry: Optional[str] = None
-    for line in hlo_text.splitlines():
-        if line.startswith("ENTRY"):
-            match = re.search(r"ENTRY\s+%?([\w.\-]+)", line)
-            if match:
-                entry = match.group(1)
-            break
-    if entry is None and parsed:
-        entry = next(iter(parsed))
+    entry, parsed = parse_module(hlo_text)
 
     ops: List[CollectiveOp] = []
     violations: List[Violation] = []
@@ -267,8 +252,7 @@ def collective_schedule_from_hlo(hlo_text: str, where: str = "<hlo>",
             base = op.opcode[:-len("-start")] \
                 if op.opcode.endswith("-start") else op.opcode
             if base in COLLECTIVES:
-                group_size, _ = _group_info(op.line, pod_size)
-                ops.append(CollectiveOp(base, (), (), group_size))
+                ops.append(CollectiveOp(base, (), (), group_size(op.line)))
                 if in_unproven_while:
                     violations.append(Violation(
                         "while-collective", where,
@@ -333,17 +317,6 @@ def repo_programs() -> List[Program]:
         fn = functools.partial(_exchange_round_fn, axis_name="data")
         return fn, (jnp.zeros((1, 1024), jnp.uint32),), (("data", 4),)
 
-    def psum_grads() -> Tuple[Callable[..., Any], Tuple[Any, ...],
-                              Tuple[Tuple[str, int], ...]]:
-        import jax.numpy as jnp
-        from ..dist.compression import compressed_psum_grads
-
-        def fn(grads: Any, errs: Any) -> Any:
-            return compressed_psum_grads(grads, errs, axis_name="data")
-
-        leaf = jnp.zeros((4, 4), jnp.float32)
-        return fn, ({"w": leaf}, {"w": jnp.zeros_like(leaf)}), (("data", 4),)
-
     return [
         Program("scale.shard._candidate_round_fn", candidate_round,
                 ("data",), expect=()),
@@ -351,10 +324,6 @@ def repo_programs() -> List[Program]:
                 ("data",), expect=()),
         Program("core.packed_reduce._exchange_round_fn", exchange_round,
                 ("data",), expect=(("all_gather", ("data",)),)),
-        Program("dist.compression.compressed_psum_grads", psum_grads,
-                ("data",),
-                expect=(("all_gather", ("data",)),
-                        ("all_gather", ("data",)))),
     ]
 
 

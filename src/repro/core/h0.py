@@ -7,9 +7,7 @@ construction: an edge either merges two components (an H0 *death*: pair
 merge edges is what the clearing step of Algorithm 3 consumes
 ("if e is in a persistence pair in H0: continue").
 
-Union-find with path halving + union by size — O(n_e α(n)) on the host.  A
-Boruvka-style label-propagation variant (JAX, log-depth, TPU-friendly) lives
-in ``jax_engine.py`` and is cross-validated in tests.
+Union-find with path halving + union by size — O(n_e α(n)) on the host.
 """
 from __future__ import annotations
 

@@ -64,8 +64,7 @@ single-device engine.  Phases per superstep:
   previous superstep (pivots arrive only through the exchange wire — see
   below), with per-slice serial passes for intra-slice collisions;
 * **tournament catch-up** — cross-slice collisions resolve in ``log2 P``
-  hypercube rounds (partner ``j XOR step``, the pairing of
-  ``core.jax_engine.make_distributed_round``): the later-ranked slice's row
+  hypercube rounds (partner ``j XOR step``): the later-ranked slice's row
   absorbs the earlier one's current (R, gens) snapshot — later batch
   columns follow earlier ones in processing order, so this matches the
   left-to-right schedule and only removes work;
@@ -98,11 +97,11 @@ from ..analyze.invariants import active_sanitizer
 from ..kernels.gf2 import (NO_LOW, find_low_np, scatter_bits,
                            scatter_xor_bits, set_bit_positions,
                            stack_wire_payloads, unstack_wire_payloads)
-from ..launch.elastic import ShardSupervisor
 from ..obs.metrics import MetricsRegistry
 from ..obs.trace import Tracer, active_tracer, critical_path, span
 from ..resilience.faults import (TransientFault, active_injector,
                                  corrupt_payload, retry_with_backoff)
+from ..resilience.supervisor import ShardSupervisor
 from .pairing import EMPTY_KEY
 from .reduction import (DimensionAdapter, PivotStore, ReductionResult,
                         clearance_commit, clearing_filter, finalize_result,
@@ -668,13 +667,13 @@ def _tournament_merge(blk: _PackedBatch, gens: List[Dict[int, int]],
                       bounds: np.ndarray) -> Tuple[int, np.ndarray]:
     """Cross-slice catch-up in ``log2 P`` hypercube rounds.
 
-    Pairing is :func:`repro.core.jax_engine.make_distributed_round`'s
-    ``(j, j XOR step)``; the later-ranked slice absorbs, because every
-    column of a later batch follows every column of an earlier one in
-    processing order — so each absorption is a legal left-to-right column
-    addition and only removes work.  Collisions the hypercube pairing does
-    not cover (and any it creates) are caught by the driver's store-probe /
-    per-slice serial-pass loop and the exact commit sweep."""
+    Pairing is the hypercube's ``(j, j XOR step)``; the later-ranked slice
+    absorbs, because every column of a later batch follows every column of
+    an earlier one in processing order — so each absorption is a legal
+    left-to-right column addition and only removes work.  Collisions the
+    hypercube pairing does not cover (and any it creates) are caught by the
+    driver's store-probe / per-slice serial-pass loop and the exact commit
+    sweep."""
     n_red = 0
     changed: set = set()
     P = len(bounds) - 1
@@ -725,22 +724,41 @@ def _exchange_round_fn(x, axis_name: str):
     return jax.lax.all_gather(x[0], axis_name)
 
 
+def reduce_specs(mesh):
+    """Specs for the pivot-exchange ``shard_map``.
+
+    The exchange round moves one ``(P, L)`` uint32 payload buffer — shard
+    ``k``'s Elias–Fano-encoded commit delta in row ``k`` — through an
+    ``all_gather`` over the same innermost data axis the tile harvest
+    shards on: in, the leading axis shards over ``data`` (each device holds
+    its own row); out, every device returns the full gathered ``(P, L)``
+    buffer, i.e. the result is replicated (spec ``P()``), which is exactly
+    the replica-install contract: every shard sees every shard's pivots.
+
+    Returns ``(in_spec, out_spec, axis_name)`` for ``jax.shard_map``.
+    """
+    from jax.sharding import PartitionSpec
+
+    from ..scale.shard import shard_of_mesh
+
+    axis, _ = shard_of_mesh(mesh)
+    return PartitionSpec(axis), PartitionSpec(), axis
+
+
 def _make_exchange(mesh, n_shards: int):
     """Pivot-exchange round: per-shard wire payloads -> all shards' payloads.
 
     With a mesh, payloads stack into a ``(P, L)`` uint32 buffer (``L``
     bucketed to a power of two so the jitted collective retraces a handful
     of times, not once per superstep) and cross-ship through
-    ``jax.lax.all_gather`` under ``shard_map`` with the reduction batch
-    specs from :func:`repro.dist.sharding.reduce_specs`.  Without a mesh
-    the exchange is the host loop-back — identical payload path (encode ->
-    exchange -> decode), no devices."""
+    ``jax.lax.all_gather`` under ``shard_map`` with the specs from
+    :func:`reduce_specs`.  Without a mesh the exchange is the host
+    loop-back — identical payload path (encode -> exchange -> decode), no
+    devices."""
     if mesh is None:
         return lambda payloads: payloads
     import jax
     import jax.numpy as jnp
-
-    from ..dist.sharding import reduce_specs
 
     in_spec, out_spec, axis = reduce_specs(mesh)
     fns: Dict[int, object] = {}

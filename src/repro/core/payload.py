@@ -1,112 +1,32 @@
-"""Compressed transport for the distributed paths.
+"""Lossless Elias–Fano codec for the distributed reduction's pivot wire.
 
-Two codecs live here, one lossy and one lossless, for two different wires:
-
-**int8 error-feedback (lossy, gradients).**  The slow axis of a multi-pod
-mesh moves gradients, and gradients tolerate lossy transport when the
-quantization error is *fed back*: each step quantizes ``g + err`` instead of
-``g`` and carries the residual to the next step, so the accumulated signal
-is unbiased (1-bit/int8 SGD with error feedback; Seide et al., Karimireddy
-et al.).
-
-``ef_compress`` quantizes to symmetric int8 with a per-tensor scale:
-
-    scale = max|g + err| / 127,  q = round((g + err) / scale)
-
-so the per-element residual is at most half a quantization step.
-
-``compressed_psum_grads`` is the wire format: inside ``shard_map`` each
-device quantizes locally, ``all_gather``s the int8 payload + f32 scales over
-``axis_name``, dequantizes per peer, and averages locally.  Per-link ring
-bytes with every device contributing a full-size gradient (R = f32 bytes):
-all-gather of the int8 buffers moves (N-1) * R/4 versus 2 * R * (N-1)/N for
-the f32 psum — an 8/N advantage, i.e. 4x at N=2.  This targets the *pod*
-(DCN) axis, which is N=2 in the production meshes; beyond N=8 a gather-based
-exchange loses and a reduce-scatter formulation would be needed (ROADMAP
-open item).  Int8 summation happens *after* dequantization, so no overflow
-at any world size.
-
-**Elias–Fano (lossless, pivot exchange).**  The distributed packed
-reduction (``core.packed_reduce``) ships committed pivot columns between
-devices once per superstep, and GF(2) pivot data tolerates *zero* loss —
-one flipped key breaks bit-identity of the diagrams.  Pivot columns are
-strictly-increasing int64 key arrays, the textbook Elias–Fano case:
-``n`` values below universe ``U`` cost ``n * (2 + ceil(log2(U/n)))`` bits —
-each key stores its low ``l = floor(log2(U/n))`` bits verbatim and its high
-bits unary in a bitvector with exactly one set bit per value
-(``high + index``), so both streams decode vectorized (``np.unpackbits`` +
-``flatnonzero``).  ``ef_encode_sorted``/``ef_decode_sorted`` are the exact
-round-trip pair; ``pack_column_payload``/``unpack_column_payload`` lift them
-to a *batch* of sorted columns by embedding column ``c``'s keys into the
-single strictly-increasing sequence ``keys + c * U`` (monotone within a
-column, and across a column boundary the ``+U`` step dominates any key
-reset), so one vectorized encode covers the whole delta — no per-column
-Python loop on the hot path.
+The distributed packed reduction (``core.packed_reduce``) ships committed
+pivot columns between devices once per superstep, and GF(2) pivot data
+tolerates *zero* loss — one flipped key breaks bit-identity of the
+diagrams.  Pivot columns are strictly-increasing int64 key arrays, the
+textbook Elias–Fano case: ``n`` values below universe ``U`` cost
+``n * (2 + ceil(log2(U/n)))`` bits — each key stores its low
+``l = floor(log2(U/n))`` bits verbatim and its high bits unary in a
+bitvector with exactly one set bit per value (``high + index``), so both
+streams decode vectorized (``np.unpackbits`` + ``flatnonzero``).
+``ef_encode_sorted``/``ef_decode_sorted`` are the exact round-trip pair;
+``pack_column_payload``/``unpack_column_payload`` lift them to a *batch* of
+sorted columns by embedding column ``c``'s keys into the single
+strictly-increasing sequence ``keys + c * U`` (monotone within a column,
+and across a column boundary the ``+U`` step dominates any key reset), so
+one vectorized encode covers the whole delta — no per-column Python loop
+on the hot path.  :mod:`repro.core.pivot_cache` frames these payloads into
+commit deltas.
 """
 from __future__ import annotations
 
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["compressed_psum_grads", "dequantize_int8", "ef_compress",
-           "ef_encode_sorted", "ef_decode_sorted",
+__all__ = ["ef_encode_sorted", "ef_decode_sorted",
            "pack_column_payload", "unpack_column_payload"]
 
-
-def ef_compress(x, err) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Quantize ``x + err`` to int8. Returns ``(q, scale, new_err)``.
-
-    ``|new_err| <= scale / 2`` elementwise, and ``dequantize_int8(q, scale)
-    + new_err == x + err`` exactly (the feedback identity).  A zero or
-    denormal-underflow scale degrades to q=0 with the full signal carried in
-    ``new_err`` — never a NaN/inf.
-    """
-    y = x.astype(jnp.float32) + err.astype(jnp.float32)
-    scale = jnp.max(jnp.abs(y)) / jnp.float32(127.0)
-    scale = jnp.where(scale > 0, scale, jnp.float32(1.0))
-    q = jnp.clip(jnp.round(y / scale), -127.0, 127.0).astype(jnp.int8)
-    new_err = y - q.astype(jnp.float32) * scale
-    return q, scale, new_err
-
-
-def dequantize_int8(q, scale) -> jnp.ndarray:
-    return q.astype(jnp.float32) * scale
-
-
-def compressed_psum_grads(grads, errs, axis_name: str) -> Tuple[Any, Any]:
-    """Mean of ``grads`` over ``axis_name`` with int8-EF transport.
-
-    Call inside ``shard_map``.  ``grads``/``errs`` are congruent pytrees;
-    returns ``(means, new_errs)`` with the same structure.  Each leaf moves
-    as (int8 payload, f32 scale) via ring all-gather — 8/N the collective
-    bytes of an f32 psum, so 4x fewer on the N=2 pod axis this is built for
-    (see module docstring for the scaling caveat) — and each device
-    reconstructs the mean locally, so the result differs from the exact
-    mean by at most one quantization step (and the difference is what
-    ``new_errs`` feeds back).
-    """
-    n = jax.lax.psum(1, axis_name)
-    flat_g, treedef = jax.tree_util.tree_flatten(grads)
-    flat_e = jax.tree_util.tree_leaves(errs)
-    assert len(flat_g) == len(flat_e), (len(flat_g), len(flat_e))
-    means, new_errs = [], []
-    for g, e in zip(flat_g, flat_e):
-        q, scale, ne = ef_compress(g, e)
-        qg = jax.lax.all_gather(q, axis_name)        # (N, ...) int8 on wire
-        sg = jax.lax.all_gather(scale, axis_name)    # (N,) f32
-        deq = qg.astype(jnp.float32) * sg.reshape((-1,) + (1,) * g.ndim)
-        means.append(deq.sum(axis=0) / n)
-        new_errs.append(ne)
-    return (jax.tree_util.tree_unflatten(treedef, means),
-            jax.tree_util.tree_unflatten(treedef, new_errs))
-
-
-# ---------------------------------------------------------------------------
-# Lossless Elias–Fano for sorted non-negative int64 sequences (pivot wire)
-# ---------------------------------------------------------------------------
 
 _EF_MAGIC = np.uint32(0xEF50)
 

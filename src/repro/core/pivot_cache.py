@@ -24,7 +24,7 @@ unit of the distributed engine:
   ``n_mat_hits`` in the bench counters).
 * **replication codec** — ``encode_commit_delta``/``decode_commit_delta``
   turn a superstep's freshly committed pivots into one flat uint32 wire
-  payload (Elias–Fano compressed, :mod:`repro.dist.compression`) and back.
+  payload (Elias–Fano compressed, :mod:`repro.core.payload`) and back.
   The distributed driver's *concurrent* phase reads pivots only through a
   replica installed from decoded payloads, so the codec is load-bearing for
   the bit-identity tests — a corrupt wire format changes diagrams, it does
@@ -41,6 +41,7 @@ import numpy as np
 from ..analyze.invariants import active_sanitizer
 from ..obs.metrics import MetricsRegistry
 from ..resilience.faults import WireCorruption
+from .payload import pack_column_payload, unpack_column_payload
 
 __all__ = ["PackedPivotCache", "encode_commit_delta", "decode_commit_delta",
            "verify_commit_delta"]
@@ -157,14 +158,12 @@ def encode_commit_delta(records: Sequence[dict]) -> np.ndarray:
     records ship their R column; implicit records ship their V generators
     (sorted for transport — generator *sets* are what parity reduction
     consumes, order is representational only).  The R columns and generator
-    lists ride one fused :func:`~repro.dist.compression.pack_column_payload`
+    lists ride one fused :func:`~repro.core.payload.pack_column_payload`
     batch (columns first, gens second) so a delta costs a constant number
     of Elias–Fano passes however many pivots it carries.  Lossless by
     construction: the bit-identity suite round-trips diagrams through this
     wire format.
     """
-    from ..dist.compression import pack_column_payload
-
     n = len(records)
     lows = np.array([r["low"] for r in records], dtype=np.int64)
     ids = np.array([r["col_id"] for r in records], dtype=np.int64)
@@ -221,8 +220,6 @@ def decode_commit_delta(payload: np.ndarray) -> List[dict]:
     ``ValueError``) on a bad magic word or checksum mismatch — a corrupt
     exchange payload is *rejected for retransmission*, never installed
     into a replica store."""
-    from ..dist.compression import unpack_column_payload
-
     w = np.ascontiguousarray(payload, dtype=np.uint32)
     if w.size < 4 or w[0] != _DELTA_MAGIC:
         raise WireCorruption("not a commit-delta payload")

@@ -168,12 +168,10 @@ class ReductionCheckpoint:
 
         Raises :class:`~repro.resilience.faults.CheckpointCorruption` on a
         truncated/unparseable file, an unsupported format version, or a
-        content-hash mismatch — callers fall back to a cold reduction (the
-        detect-corrupt -> fall-back-to-cold contract shared with
-        ``checkpoint.Checkpointer``).  The ``resume.load`` injection point
-        fires here, corrupting the *in-memory* read buffer so tests and
-        the chaos soak exercise every rejection path without touching the
-        file on disk."""
+        content-hash mismatch — callers fall back to a cold reduction.
+        The ``resume.load`` injection point fires here, corrupting the
+        *in-memory* read buffer so tests and the chaos soak exercise every
+        rejection path without touching the file on disk."""
         global _LOAD_ORDINAL
         _LOAD_ORDINAL += 1
         with open(path, "rb") as f:

@@ -6,8 +6,7 @@ processed per round:
 * **parallel** phase — every batch column is reduced against the already
   committed ``R^⊥`` (and against trivial owners) independently; this is the
   embarrassingly-parallel part the paper maps to threads and we map to
-  vectorized/batched work (and, in ``jax_engine.py``, to the ``data`` mesh
-  axis via ``shard_map``).
+  vectorized/batched work.
 * **serial** phase — intra-batch pivot collisions are resolved in filtration
   order: a column may only absorb a *marked* (fully reduced) earlier batch
   mate, falling back to the parallel rule whenever its new low re-enters the

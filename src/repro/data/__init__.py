@@ -1,1 +1,1 @@
-"""Data substrates: point clouds (paper benchmarks) + LM token pipeline."""
+"""Data substrates: the paper's benchmark point clouds."""

@@ -1,3 +1,2 @@
 """Pallas TPU kernels for the compute hot spots (pairwise distances, GF(2)
-bit-packed reduction, flash attention) with jit wrappers (ops) and pure-jnp
-oracles (ref)."""
+bit-packed reduction) with jit wrappers (ops) and pure-jnp oracles (ref)."""

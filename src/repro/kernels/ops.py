@@ -2,9 +2,8 @@
 
 On the CPU container Pallas executes in interpret mode (correctness); on TPU
 the same ``pl.pallas_call`` compiles to Mosaic.  ``use_pallas`` resolves to
-False on CPU *for jit-compiled production paths* (dry-run lowerings use the
-pure-jnp reference so the HLO reflects XLA's native lowering), while tests
-force interpret-mode kernels to validate them.
+False off TPU, where the wrappers fall back to the pure-jnp/numpy oracles,
+while tests force interpret-mode kernels to validate them.
 """
 from __future__ import annotations
 
@@ -16,7 +15,6 @@ import numpy as np
 
 from . import ref as kref
 from .backend import default_interpret  # noqa: F401  (re-export)
-from .flash_attention import flash_attention
 from .gf2 import gf2_find_low, gf2_serial_reduce
 from .pairwise_dist import pairwise_sq_dists
 
@@ -59,16 +57,3 @@ def serial_reduce_bits(blocks, use_pallas=None, interpret=None):
         return jnp.asarray(b), jnp.asarray(l), jnp.asarray(r)
     return gf2_serial_reduce(jnp.asarray(blocks), interpret=interpret)
 
-
-def attention(q, k, v, causal: bool = True, window: int = -1,
-              use_pallas=None, interpret=None,
-              block_q: int = 128, block_k: int = 128) -> jnp.ndarray:
-    """(BH, S, d) attention; Pallas flash kernel or jnp reference."""
-    use_pallas = default_use_pallas() if use_pallas is None else use_pallas
-    if not use_pallas:
-        return kref.attention_ref(q, k, v, causal=causal, window=window)
-    # blocks pass through unshrunk: the kernel pads ragged/short S itself,
-    # keeping Pallas blocks MXU-aligned on the compiled (TPU) path
-    return flash_attention(q, k, v, causal=causal, window=window,
-                           block_q=block_q, block_k=block_k,
-                           interpret=interpret)

@@ -1,7 +1,6 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -48,21 +47,3 @@ def gf2_serial_reduce_ref(blocks: np.ndarray):
             lows[g, c] = low
     return blocks, lows, reds
 
-
-def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                  causal: bool = True, window: int = -1) -> jnp.ndarray:
-    """Naive softmax attention. q,k,v: (BH, S, d)."""
-    d = q.shape[-1]
-    s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) / jnp.sqrt(jnp.float32(d))
-    sq, sk = q.shape[1], k.shape[1]
-    q_idx = jnp.arange(sq)[:, None]
-    k_idx = jnp.arange(sk)[None, :]
-    mask = jnp.ones((sq, sk), dtype=bool)
-    if causal:
-        mask &= k_idx <= q_idx
-    if window > 0:
-        mask &= (q_idx - k_idx) < window
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", p, v.astype(jnp.float32)).astype(q.dtype)

@@ -1,19 +1,12 @@
-"""Batched serving drivers over synthetic traffic.
+"""PH serving driver over synthetic traffic.
 
-Two workloads share the launcher:
-
-* ``--workload tokens`` — the transformer ``ServeEngine`` (fixed-slot
-  continuous batching over a shared KV cache).
-* ``--workload ph`` — ``PHServeEngine``: admission-controlled persistent
-  homology serving with union-batched cold requests and warm-start
-  incremental updates (tau growth / point arrival) against the dataset
-  cache.
+``PHServeEngine``: admission-controlled persistent homology serving with
+union-batched cold requests and warm-start incremental updates (tau growth
+/ point arrival) against the dataset cache.
 
 Usage::
 
-    PYTHONPATH=src python -m repro.launch.serve --workload tokens \
-        --arch qwen3-0.6b --requests 16 --max-new 24
-    PYTHONPATH=src python -m repro.launch.serve --workload ph \
+    PYTHONPATH=src python -m repro.launch.serve \
         --requests 24 --cloud-size 48 --update-fraction 0.5
 """
 from __future__ import annotations
@@ -23,32 +16,6 @@ import argparse
 import numpy as np
 
 from repro.obs.trace import stopwatch
-
-
-def run_tokens(args) -> None:
-    from repro.configs import get_config
-    from repro.serve.engine import Request, ServeEngine
-
-    cfg = get_config(args.arch, reduced=True)
-    engine = ServeEngine(cfg, max_batch=args.max_batch,
-                         prompt_len=args.prompt_len, s_max=args.s_max,
-                         seed=args.seed)
-    rng = np.random.default_rng(args.seed)
-    for uid in range(args.requests):
-        prompt = rng.integers(0, cfg.vocab_size,
-                              size=rng.integers(4, args.prompt_len),
-                              dtype=np.int32)
-        engine.submit(Request(uid=uid, prompt=prompt, max_new=args.max_new))
-
-    with stopwatch("serve/run") as sw:
-        done = engine.run()
-    wall = sw.elapsed
-    total_tokens = sum(len(v) for v in done.values())
-    print(f"served {len(done)}/{args.requests} requests, "
-          f"{total_tokens} tokens in {wall:.2f}s "
-          f"({total_tokens / wall:.1f} tok/s batched on CPU)")
-    for uid in sorted(done)[:4]:
-        print(f"  req {uid}: {done[uid][:12]}...")
 
 
 def run_ph(args) -> None:
@@ -103,16 +70,8 @@ def run_ph(args) -> None:
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--workload", choices=("tokens", "ph"), default="tokens")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
-    # tokens workload
-    ap.add_argument("--arch", default="qwen3-0.6b")
-    ap.add_argument("--max-batch", type=int, default=8)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--max-new", type=int, default=24)
-    ap.add_argument("--s-max", type=int, default=128)
-    # ph workload
     ap.add_argument("--cloud-size", type=int, default=48)
     ap.add_argument("--tau", type=float, default=1.6)
     ap.add_argument("--arrivals", type=int, default=6,
@@ -129,11 +88,7 @@ def main() -> None:
                     choices=("single", "batch", "packed"))
     ap.add_argument("--batch-size", type=int, default=64)
     ap.add_argument("--n-shards", type=int, default=None)
-    args = ap.parse_args()
-    if args.workload == "tokens":
-        run_tokens(args)
-    else:
-        run_ph(args)
+    run_ph(ap.parse_args())
 
 
 if __name__ == "__main__":

@@ -2,7 +2,7 @@
 
 Every ``stats`` producer in the pipeline (``core/reduction.py``,
 ``core/serial_parallel.py``, ``core/packed_reduce.py``,
-``core/pivot_cache.py``, ``core/homology.py``, ``serve/engine.py``) builds
+``core/pivot_cache.py``, ``core/homology.py``, ``serve/ph.py``) builds
 its numbers through a :class:`MetricsRegistry` instead of an ad-hoc dict,
 so every emitted key has a declared kind (counter / gauge / histogram), a
 unit, and one line of documentation — :data:`SCHEMA` below *is* the schema
@@ -143,13 +143,6 @@ SCHEMA: Dict[str, MetricSpec] = {s.name: s for s in [
           "observed reduction high-water: store + packed block, max over dims"),
     _spec("budget_drift_ratio", "gauge", "ratio",
           "(base + worst observed transient) / predicted account"),
-    # -- serving engine --
-    _spec("serve_n_prefills", "counter", "batches", "prefill launches"),
-    _spec("serve_n_decode_steps", "counter", "steps", "decode steps run"),
-    _spec("serve_n_tokens", "counter", "tokens", "tokens decoded"),
-    _spec("serve_n_completed", "counter", "requests", "requests completed"),
-    _spec("serve_tokens_per_request", "histogram", "tokens",
-          "decoded tokens per completed request"),
     # -- PH serving engine (repro.serve.ph) --
     _spec("serve_ph_n_requests", "counter", "requests",
           "PH requests submitted"),

@@ -1,4 +1,5 @@
-"""repro.resilience — deterministic fault injection + recovery primitives.
+"""repro.resilience — deterministic fault injection, shard supervision and
+recovery primitives.
 
 See ``docs/resilience.md`` for the fault model, the recovery line in the
 distributed reduction, and the serving degradation contract."""

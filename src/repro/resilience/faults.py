@@ -87,9 +87,8 @@ class WireCorruption(ValueError):
 class CheckpointCorruption(ValueError):
     """A checkpoint failed its integrity check (hash, version, truncation).
 
-    Raised by ``ReductionCheckpoint.load`` and
-    ``checkpoint.Checkpointer.restore`` — callers fall back to an older
-    step or a cold reduction, never to silently wrong state."""
+    Raised by ``ReductionCheckpoint.load`` — callers fall back to a cold
+    reduction, never to silently wrong state."""
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,20 +1,19 @@
-"""Fault tolerance primitives: heartbeat supervision, shard supervision for
-the distributed reduction, speculative straggler reassignment.
+"""Shard supervision for the distributed reduction: heartbeats, shard
+death, speculative straggler reassignment.
 
-This module is imported by ``repro.core.packed_reduce`` — the distributed
-packed GF(2) driver wires :class:`ShardSupervisor` into its superstep
-loop (see ``docs/resilience.md``): every live shard beats once per
-superstep on a *deterministic superstep-indexed clock*, dead shards are
-detected by beat timeout and their remaining batch queue is re-dealt to
-survivors from the last exact commit sweep, and stragglers are sidelined
-for a cooldown so the fused superstep stops synchronizing on the slowest
-host.  At production scale DCN heartbeats and the cluster scheduler
-replace the in-process clock; the recovery algebra (re-deal from the last
-commit sweep, exact-by-construction replica staleness) is identical.
+``repro.core.packed_reduce``'s distributed packed GF(2) driver wires
+:class:`ShardSupervisor` into its superstep loop (see
+``docs/resilience.md``): every live shard beats once per superstep on a
+*deterministic superstep-indexed clock*, dead shards are detected by beat
+timeout and their remaining batch queue is re-dealt to survivors from the
+last exact commit sweep, and stragglers are sidelined for a cooldown so the
+fused superstep stops synchronizing on the slowest host.  At production
+scale DCN heartbeats and the cluster scheduler replace the in-process
+clock; the recovery algebra (re-deal from the last commit sweep,
+exact-by-construction replica staleness) is identical.
 
 Import discipline: dependency-light (stdlib + numpy) — no jax, no
-side effects.  Anything that forces device counts belongs in the caller's
-environment, not here.
+side effects.
 """
 import dataclasses
 import time

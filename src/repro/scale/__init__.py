@@ -16,7 +16,7 @@ from .budget import (account_bytes, edge_budget, estimate_tau_max,
                      maxmin_landmarks, sample_pair_lengths,
                      sharded_edge_budget, tile_transient_bytes)
 from .shard import (build_filtration_sharded, harvest_edges_sharded,
-                    partition_tiles, shard_of_mesh)
+                    make_data_mesh, partition_tiles, shard_of_mesh)
 from .sparse_input import (build_filtration_coo, contacts_to_distances,
                            coo_symmetrize)
 from .tiles import (TileStats, build_filtration_tiled, harvest_edges,
@@ -26,7 +26,7 @@ __all__ = [
     "TileStats", "build_filtration_tiled", "harvest_edges", "iter_tile_edges",
     "merge_edge_chunks", "tile_grid",
     "build_filtration_sharded", "harvest_edges_sharded", "partition_tiles",
-    "shard_of_mesh",
+    "make_data_mesh", "shard_of_mesh",
     "account_bytes", "edge_budget", "estimate_tau_max", "maxmin_landmarks",
     "landmark_points",
     "sample_pair_lengths", "sharded_edge_budget", "tile_transient_bytes",

@@ -12,7 +12,7 @@ Execution model (one *round* = one tile per device):
 
 * the FLOP-dominant f32 candidate tiles are computed **on device** under
   ``jax.shard_map``: point blocks for the round are stacked on a leading
-  axis sharded over ``data`` (specs from ``repro.dist.sharding.tile_specs``)
+  axis sharded over ``data`` (specs from :func:`tile_specs`)
   and each device runs the Pallas ``pairwise_sq_dists`` kernel on its own
   block pair — no cross-device communication inside a round;
 * the round's stacked f32 output is gathered back to the host (this is the
@@ -48,7 +48,7 @@ from .tiles import (DEFAULT_TILE, TileStats, _f32_dists_threshold,
                     tile_grid)
 
 __all__ = ["build_filtration_sharded", "harvest_edges_sharded",
-           "partition_tiles", "shard_of_mesh"]
+           "make_data_mesh", "partition_tiles", "shard_of_mesh", "tile_specs"]
 
 
 def partition_tiles(n: int, tile_m: int, tile_n: int,
@@ -68,10 +68,53 @@ def partition_tiles(n: int, tile_m: int, tile_n: int,
     return [tiles[k::n_shards] for k in range(n_shards)]
 
 
+def make_data_mesh(n_devices: Optional[int] = None):
+    """1-D ``(data,)`` mesh over the first ``n_devices`` (default: all).
+
+    The mesh shape this module and ``compute_ph(..., backend="tiled",
+    mesh=...)`` expect for sharding the tile harvest; on a CPU host,
+    virtual devices come from
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set before jax
+    initializes — the 4-device CI job does this).
+    """
+    import jax
+    from jax.sharding import Mesh
+
+    devices = jax.devices()
+    n = len(devices) if n_devices is None else int(n_devices)
+    if len(devices) < n:
+        raise RuntimeError(f"need {n} devices for a (data={n},) mesh, "
+                           f"have {len(devices)}")
+    return Mesh(np.array(devices[:n]), ("data",))
+
+
+def tile_specs(mesh):
+    """Specs for the sharded tile-harvest ``shard_map``.
+
+    One round stacks each device's ``(tile_m, d)`` / ``(tile_n, d)`` point
+    blocks on a leading axis of size ``data``; that leading axis shards over
+    the innermost data axis (``data`` when present, else ``pod``) and
+    everything else — including any ``model`` axis present — sees the work
+    replicated (tile harvesting is pure data parallelism; other axes
+    contribute nothing and must not split a tile).
+
+    Returns ``(in_specs, out_specs, axis_name)`` ready to pass to
+    ``jax.shard_map``: ``in_specs`` for the (x-blocks, y-blocks) pair,
+    ``out_specs`` for the stacked ``(data, tile_m, tile_n)`` output.
+    """
+    from jax.sharding import PartitionSpec
+
+    names = tuple(getattr(mesh, "axis_names", ()))
+    data_axes = [a for a in ("pod", "data") if a in names]
+    if not data_axes:
+        raise ValueError(f"mesh axes {names} have no data axis to shard "
+                         "over")
+    spec = PartitionSpec(data_axes[-1])
+    return (spec, spec), spec, data_axes[-1]
+
+
 def shard_of_mesh(mesh) -> Tuple[str, int]:
     """(axis name, size) of the mesh axis tiles shard over (the data axis)."""
-    from ..dist.sharding import tile_specs
-
     _, _, axis = tile_specs(mesh)
     return axis, int(mesh.shape[axis])
 
@@ -132,8 +175,6 @@ def _harvest_shards_device(points, sq, shards, tau_max, tile_m, tile_n,
 
     import jax
     import jax.numpy as jnp
-
-    from ..dist.sharding import tile_specs
 
     n, d = points.shape
     n_shards = len(shards)
@@ -196,8 +237,6 @@ def _harvest_shards_device_dists(dists, shards, tau_max, tile_m, tile_n,
 
     import jax
     import jax.numpy as jnp
-
-    from ..dist.sharding import tile_specs
 
     n = dists.shape[0]
     n_shards = len(shards)

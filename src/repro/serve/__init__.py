@@ -1,5 +1,2 @@
-from .steps import (extend_cache, make_decode_step, make_prefill_step,
-                    sample_greedy, sample_temperature)
-from .engine import ServeEngine, Request
 from .ph import (AdmissionDecision, PHRequest, PHResponse, PHServeEngine,
                  fingerprint_points)

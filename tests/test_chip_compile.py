@@ -38,9 +38,8 @@ def one_chip():
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     # a described chip's executables cannot be read back from the
     # persistent cache: keep these compiles out of it.  And compile in
-    # JAX's default 32-bit mode, which the device path runs in:
-    # importing core/jax_engine.py turns x64 on for the whole process,
-    # and Mosaic refuses the 64-bit index maps kernels then trace to
+    # JAX's default 32-bit mode, which the device path runs in: under
+    # x64 Mosaic refuses the 64-bit index maps kernels trace to
     prev = (jax.config.jax_enable_compilation_cache,
             jax.config.jax_enable_x64)
     jax.config.update("jax_enable_compilation_cache", False)

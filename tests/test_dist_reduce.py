@@ -44,7 +44,7 @@ def _data_mesh(n_devices):
     if len(jax.devices()) < n_devices:
         pytest.skip(f"needs {n_devices} devices (run under XLA_FLAGS="
                     f"--xla_force_host_platform_device_count={n_devices})")
-    from repro.launch.mesh import make_data_mesh
+    from repro.scale import make_data_mesh
     return make_data_mesh(n_devices)
 
 

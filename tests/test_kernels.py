@@ -7,7 +7,6 @@ from hypothesis import given, settings, strategies as st
 import jax.numpy as jnp
 
 from repro.kernels import ref as kref
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gf2 import (find_low_np, gf2_find_low, gf2_parallel_xor,
                                gf2_serial_reduce)
 from repro.kernels.pairwise_dist import pairwise_sq_dists
@@ -217,49 +216,6 @@ def test_compile_cache_dir_fixed_across_cwd_and_process(tmp_path):
     assert seen == {f"{want} {want}"}
 
 
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("s,d,bq,bk", [(128, 64, 64, 64), (256, 32, 128, 128)])
-def test_flash_attention_kernel(causal, s, d, bq, bk):
-    rng = np.random.default_rng(7)
-    q = jnp.asarray(rng.normal(size=(2, s, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(2, s, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(2, s, d)), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk,
-                          interpret=True)
-    expect = kref.attention_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_flash_attention_window():
-    rng = np.random.default_rng(8)
-    q = jnp.asarray(rng.normal(size=(1, 256, 32)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(1, 256, 32)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(1, 256, 32)), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, window=64, block_q=64,
-                          block_k=64, interpret=True)
-    expect = kref.attention_ref(q, k, v, causal=True, window=64)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4), (jnp.bfloat16, 3e-2)])
-def test_flash_attention_dtypes(dtype, tol):
-    rng = np.random.default_rng(9)
-    q = jnp.asarray(rng.normal(size=(1, 128, 64)), dtype)
-    k = jnp.asarray(rng.normal(size=(1, 128, 64)), dtype)
-    v = jnp.asarray(rng.normal(size=(1, 128, 64)), dtype)
-    out = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
-    expect = kref.attention_ref(q, k, v)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(expect, np.float32),
-                               rtol=tol, atol=tol)
-
-
 def test_kernel_vs_engine_distance_path():
     """The Pallas distance kernel feeds the PH engine identically to the
     numpy path (filtration-level end-to-end check)."""
@@ -272,20 +228,3 @@ def test_kernel_vs_engine_distance_path():
     a = compute_ph(points=pts, maxdim=1)
     b = compute_ph(dists=np.asarray(d_pallas, np.float64), maxdim=1)
     assert_diagrams_equal(a.diagrams, b.diagrams, dims=[0, 1], atol=1e-5)
-
-
-@settings(max_examples=12, deadline=None)
-@given(st.integers(1, 3), st.sampled_from([64, 128, 192]),
-       st.sampled_from([32, 64, 128]), st.booleans(),
-       st.integers(0, 2**31 - 1))
-def test_flash_attention_hypothesis_sweep(b, s, d, causal, seed):
-    """Property sweep: kernel == oracle across random (B, S, D, causal)."""
-    rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.normal(size=(b, s, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(b, s, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, s, d)), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
-                          interpret=True)
-    expect = kref.attention_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               rtol=3e-4, atol=3e-4)

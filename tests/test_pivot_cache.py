@@ -13,6 +13,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.analyze import sanitizing
+from repro.core.payload import pack_column_payload, unpack_column_payload
 from repro.core.pivot_cache import (PackedPivotCache, decode_commit_delta,
                                     encode_commit_delta)
 from repro.core.reduction import PivotStore
@@ -67,6 +68,25 @@ def test_delta_empty_column_and_gens():
                {"low": 3, "col_id": 4, "mode": "implicit",
                 "column": None, "gens": np.zeros(0, dtype=np.int64)}]
     _records_equal(records, decode_commit_delta(encode_commit_delta(records)))
+
+
+@pytest.mark.parametrize("columns", [
+    [],
+    [np.zeros(0, dtype=np.int64)],
+    [np.array([7], dtype=np.int64)],
+    [np.sort(np.random.default_rng(3).choice(10**9, size=10_000,
+                                             replace=False))],
+    [np.array([2**63 - 3, 2**63 - 2, 2**63 - 1], dtype=np.int64)],
+], ids=["no-columns", "empty-column", "one-key", "10000-keys",
+        "keys-near-2^63"])
+def test_column_payload_roundtrip(columns):
+    """The Elias–Fano column codec under the delta round-trips every
+    boundary it can reach, the raw fallback past 2**62 included."""
+    back = unpack_column_payload(pack_column_payload(columns))
+    assert len(back) == len(columns)
+    for sent, got in zip(columns, back):
+        assert got.dtype == np.int64
+        np.testing.assert_array_equal(got, sent)
 
 
 def test_delta_max_key_boundary_takes_raw_fallback():

@@ -33,6 +33,7 @@ from repro.resilience.faults import (CheckpointCorruption, FaultInjector,
                                      WireCorruption, backoff_delays,
                                      corrupt_payload, flip_bit, inject,
                                      retry_with_backoff)
+from repro.resilience.supervisor import ShardSupervisor, speculative_reassign
 from repro.serve.ph import PHRequest, PHServeEngine
 
 
@@ -201,6 +202,43 @@ class TestFaultPlanDeterminism:
         with pytest.raises(TransientFault):
             retry_with_backoff(lambda a: (_ for _ in ()).throw(
                 TransientFault("always")), attempts=2, sleep=None)
+
+
+# ---------------------------------------------------------------------------
+# shard supervision
+# ---------------------------------------------------------------------------
+
+def test_straggler_policy():
+    """ShardSupervisor detects the lagging shard on a deterministic clock
+    and speculative_reassign duplicates its work onto the least-loaded
+    survivor (the policy the packed reduction driver uses)."""
+    sup = ShardSupervisor(n_shards=4, timeout=100.0, factor=3.0)
+    now = 10.0
+    plan = sup.observe(now, beats={h: now - (2.0 if h == 2 else 0.1)
+                                   for h in range(4)})
+    assert plan.dead == []
+    assert plan.stragglers == [2]
+    assert plan.active == [0, 1, 3]          # sidelined, not dead
+    assignment = {h: [i for i in range(16) if i % 4 == h] for h in range(4)}
+    backups = speculative_reassign(assignment, plan.stragglers)
+    assert 2 in backups
+    assert set(assignment[backups[2]]) >= {2, 6, 10, 14}
+    # the sideline expires: shard 2 beats on time next superstep
+    later = now + sup.sideline + 1.0
+    plan2 = sup.observe(later, beats={h: later for h in range(4)})
+    assert plan2.active == [0, 1, 2, 3]
+
+
+def test_shard_supervisor_death_is_permanent():
+    sup = ShardSupervisor(n_shards=4, timeout=1.5)
+    # shard 3 stops beating at t=1; dead once lag > timeout
+    for t in (1.0, 2.0, 3.0):
+        plan = sup.observe(t, beats={h: t for h in range(4) if h != 3})
+    assert 3 not in sup.live
+    assert plan.active == [0, 1, 2]
+    # it never comes back, even if a stale beat arrives
+    plan = sup.observe(4.0, beats={h: 4.0 for h in range(4)})
+    assert sup.live == [0, 1, 2] and plan.dead == []
 
 
 # ---------------------------------------------------------------------------

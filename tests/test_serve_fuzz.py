@@ -80,7 +80,7 @@ def _response_signature(eng):
     return sig
 
 
-@settings(max_examples=3)
+@settings(max_examples=3, deadline=None)
 @given(st.integers(0, 10_000), st.integers(6, 10), st.integers(1, 3))
 def test_stream_determinism(seed, n_requests, n_datasets):
     reqs = _gen_stream(np.random.default_rng(seed), n_requests, n_datasets)
@@ -93,7 +93,7 @@ def test_stream_determinism(seed, n_requests, n_datasets):
          for d in eng_b.admission_log]
 
 
-@settings(max_examples=3)
+@settings(max_examples=3, deadline=None)
 @given(st.integers(0, 10_000), st.integers(5, 9))
 def test_every_path_matches_cold_compute(seed, n_requests):
     reqs = _gen_stream(np.random.default_rng(seed), n_requests, 2)
@@ -110,7 +110,7 @@ def test_every_path_matches_cold_compute(seed, n_requests):
                 (req.uid, r.path, d)
 
 
-@settings(max_examples=3)
+@settings(max_examples=3, deadline=None)
 @given(st.integers(0, 10_000), st.sampled_from([20_000, 60_000, 200_000]))
 def test_tenant_bytes_never_exceed_store_budget(seed, budget):
     reqs = _gen_stream(np.random.default_rng(seed), 8, 3)
@@ -123,7 +123,7 @@ def test_tenant_bytes_never_exceed_store_budget(seed, budget):
         sum(tb.values()))
 
 
-@settings(max_examples=3)
+@settings(max_examples=3, deadline=None)
 @given(st.integers(0, 10_000), st.sampled_from([800, 3_000, 12_000]))
 def test_rejections_reproducible_from_admission_log(seed, budget):
     rng = np.random.default_rng(seed)
