@@ -19,9 +19,11 @@ block for its whole reduction:
 * **parallel phase** — one :meth:`PivotStore.lookup_addends_batched` probe
   per round (one ``owner_of_low`` / ``min_cobdy`` / ``cobdy`` call for the
   whole batch), then the hit rows absorb their gathered committed-pivot
-  addends: an in-place bit scatter-XOR on host, ``gf2_parallel_xor`` on the
-  gathered addend block on TPU, which returns the rows' new lows with their
-  sums.  Only rows whose low moved are probed again;
+  addends by an in-place bit scatter-XOR into the host block, followed by a
+  find-low over the hit rows — on every platform: the add is a few thousand
+  words, far less than one device round trip costs, and the next probe
+  needs the lows on the host anyway.  Only rows whose low moved are probed
+  again;
 * **segmented growth vs eviction** — an addend with keys outside the
   bit-space either *expands* the space (the new keys append as a fresh
   word-aligned segment; no re-ranking, lows become a min over per-segment
@@ -31,7 +33,7 @@ block for its whole reduction:
   e.g. H1* on a near-clique) evict — one stubborn chain must not balloon
   the whole block's bit-space.  Segments consolidate to one sorted universe
   only past ``_MAX_SEGMENTS`` — or eagerly on the kernel path, where the
-  kernels need the single globally-sorted bit-space;
+  serial kernel needs the single globally-sorted bit-space;
 * **serial phase** — intra-batch low collisions resolve in one host walk
   over the batch in filtration order (a ``low -> row`` dict; packed rows
   XOR whole block rows, evicted rows ``merge_cancel``), with gens updated
@@ -141,8 +143,7 @@ def _budgeted_batch_size(batch_size: int, cob_width: int,
                          store_budget_bytes: Optional[int]) -> int:
     """Cap the batch so the resident bit block fits the byte budget.
 
-    The batch block is ``B`` rows × ``~B·K/32`` words ≈ ``B²K/8`` bytes
-    (plus the same again transiently for a kernel-path addend gather).
+    The batch block is ``B`` rows × ``~B·K/32`` words ≈ ``B²K/8`` bytes.
     Inverting for ``B`` bounds the packed-block scratch the same way
     ``h2_columns`` bounds its enumeration scratch; neither changes the
     output.  Best-effort: the batch never shrinks below 32 rows (a
@@ -193,8 +194,9 @@ class _PackedBatch:
         self.n_consolidations = 0
         self.n_expansions = 0
         self.n_evictions = 0
-        self.n_device_calls = 0   # gf2 kernel round trips
-        self.n_kernel_lows = 0    # rows whose low came back with a gf2 call
+        self.n_device_calls = 0   # gf2 kernel round trips (serial pre-pass)
+        self.n_kernel_lows = 0    # rows whose low came back from the pre-pass
+        self.n_host_xor_rounds = 0   # rounds whose packed rows XORed in place
 
     # -- universe bookkeeping ------------------------------------------------
 
@@ -223,9 +225,8 @@ class _PackedBatch:
     def consolidate(self) -> None:
         """Merge all segments into one sorted universe (one global remap).
         The kernel path runs consolidated always: the lows
-        ``gf2_parallel_xor`` / ``gf2_serial_reduce`` return are first set
-        *bits*, which equal the min *key* only in a single globally-sorted
-        bit-space."""
+        ``gf2_serial_reduce`` returns are first set *bits*, which equal the
+        min *key* only in a single globally-sorted bit-space."""
         if len(self.segs) == 1:
             return
         self.n_consolidations += 1
@@ -304,10 +305,11 @@ class _PackedBatch:
     # -- lows ----------------------------------------------------------------
 
     def refresh_lows(self, rows: np.ndarray) -> None:
-        """Recompute ``lows[rows]`` (packed rows, host path) as the min key
-        over per-segment find-lows.  The kernel path never calls it: each
-        gf2 call returns the lows of the rows it wrote
-        (:meth:`_set_kernel_lows`)."""
+        """Recompute ``lows[rows]`` (packed rows) as the min key over
+        per-segment find-lows.  Each segment is scanned over its own words
+        only, so the V-words at the block's tail never register a low, and
+        a first set bit past a segment's universe (slack) counts as empty —
+        the same keys :meth:`_set_kernel_lows` gives on one segment."""
         rows = np.asarray(rows, dtype=np.int64)
         if not rows.size:
             return
@@ -317,16 +319,16 @@ class _PackedBatch:
                 continue
             w = _words(len(seg), self.use_kernels)
             lb = find_low_np(self.block[rows, off:off + w])
-            k = np.where(lb == NO_LOW, EMPTY_KEY,
+            k = np.where((lb == NO_LOW) | (lb >= len(seg)), EMPTY_KEY,
                          seg[np.minimum(lb, len(seg) - 1)])
             best = np.minimum(best, k)
         self.lows[rows] = np.where(best == EMPTY_KEY, -1, best)
 
     def _set_kernel_lows(self, rows: List[int], lb: np.ndarray) -> None:
-        """Set ``lows[rows]`` from the first-set-bit ranks a gf2 kernel
-        returned on the kernel path's single segment: -1 for NO_LOW and for
-        ranks past the universe (zero slack words, or the V-words of an
-        R-empty row)."""
+        """Set ``lows[rows]`` from the first-set-bit ranks the serial
+        kernel returned on the kernel path's single segment: -1 for NO_LOW
+        and for ranks past the universe (zero slack words, or the V-words of
+        an R-empty row)."""
         seg = self.segs[0]
         inside = lb < len(seg)
         keys = np.full(len(lb), -1, dtype=np.int64)
@@ -353,8 +355,9 @@ class _PackedBatch:
                     addends: List[Optional[np.ndarray]],
                     addend_lows: Optional[np.ndarray] = None) -> None:
         """Parallel-phase GF(2) add: gathered addends into the hit rows —
-        an in-place scatter-XOR on host, ``gf2_parallel_xor`` on a packed
-        addend block on the kernel path; scalar rows ``merge_cancel``.
+        packed rows by an in-place scatter-XOR into the host block and a
+        find-low over those rows (:meth:`refresh_lows`), on the kernel path
+        too; scalar rows ``merge_cancel``.
 
         Addend keys outside every segment either append as a fresh segment
         (dense rounds) or evict their rows (sparse rounds, ``_EVICT_MAX``).
@@ -447,35 +450,10 @@ class _PackedBatch:
                 ridx, pos = mridx, mpos
                 packed_hit = list(memo_rows)
         if packed_hit:
-            if self.use_kernels:
-                import jax
-                import jax.numpy as jnp
-
-                from ..kernels.gf2 import gf2_parallel_xor
-                local = {r: k for k, r in enumerate(packed_hit)}
-                lrid = np.array([local[int(r)] for r in ridx],
-                                dtype=np.int64)
-                order = np.lexsort((pos, lrid))
-                n_hit = len(packed_hit)
-                rows = -(-n_hit // 32) * 32   # bucket row counts for the jit
-                packed = np.zeros((rows, self.cap), dtype=np.uint32)
-                scatter_bits(packed, lrid[order], pos[order])
-                self.peak_bytes = max(self.peak_bytes,
-                                      self.block.nbytes + 2 * packed.nbytes)
-                rview = self.block[:, :self.cap]
-                cols = np.zeros_like(packed)
-                cols[:n_hit] = rview[packed_hit]
-                with span("gf2/xor"):
-                    # analyze: allow[host-sync] lows gate the host serial pass; one sync brings the sum and its lows back together
-                    xored, lb = jax.device_get(gf2_parallel_xor(
-                        jnp.asarray(cols), jnp.asarray(packed)))
-                    rview[packed_hit] = xored[:n_hit]
-                self.n_device_calls += 1
-                self._set_kernel_lows(packed_hit, lb[:n_hit])
-            else:
-                order = np.lexsort((pos, ridx))
-                scatter_xor_bits(self.block, ridx[order], pos[order])
-                self.refresh_lows(np.asarray(packed_hit, dtype=np.int64))
+            order = np.lexsort((pos, ridx))
+            scatter_xor_bits(self.block, ridx[order], pos[order])
+            self.refresh_lows(np.asarray(packed_hit, dtype=np.int64))
+            self.n_host_xor_rounds += 1
         for i in scalar_hit:
             merged = merge_cancel(self.scalar[i], addends[i])
             self.scalar[i] = merged
@@ -828,7 +806,7 @@ def reduce_dimension_packed(
     ``compute_ph(trace=...)`` renders as P parallel device lanes — and
     ``sim_wall_s`` is *derived* from that span timeline
     (:func:`repro.obs.trace.critical_path`).  Leaf spans of the host work
-    and of each gf2 kernel round trip (``reduce/probe``, ``gf2/xor``, ...)
+    and of each gf2 kernel round trip (``reduce/probe``, ``gf2/serial``, ...)
     go to the caller's tracer only and carry no ``step``, so they never
     enter that accounting.
     """
@@ -913,6 +891,7 @@ def reduce_dimension_packed(
     max_block_words = 0
     n_device_calls = 0
     n_kernel_lows = 0
+    n_host_xor_rounds = 0
     reg = MetricsRegistry()
     queue = clearing_filter(column_ids, cleared)
     eff_batch = batch_size
@@ -1256,6 +1235,7 @@ def reduce_dimension_packed(
         n_evictions += batchblk.n_evictions
         n_device_calls += batchblk.n_device_calls
         n_kernel_lows += batchblk.n_kernel_lows
+        n_host_xor_rounds += batchblk.n_host_xor_rounds
 
         frac = np.asarray(wt, dtype=np.float64)
         step_conc = float(np.max(t_fused * frac + t_slice[:n_slices]))
@@ -1362,6 +1342,7 @@ def reduce_dimension_packed(
     reg.gauge("use_kernels").set(float(use_kernels))
     reg.counter("n_device_calls").inc(n_device_calls)
     reg.counter("n_kernel_lows").inc(n_kernel_lows)
+    reg.counter("n_host_xor_rounds").inc(n_host_xor_rounds)
     reg.gauge("n_shards").set(P)
     reg.counter("n_supersteps").inc(n_supersteps)
     reg.counter("n_exchange_rounds").inc(n_exchange_rounds)

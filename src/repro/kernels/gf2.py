@@ -16,7 +16,11 @@ matrix entries.  Three kernels:
 * ``gf2_parallel_xor`` — the *parallel phase* counterpart: XOR a column
   block against a gathered addend block (each batch column against the
   committed pivot column owning its low) in one elementwise VREG pass,
-  returning each row's new low from the same pass.
+  returning each row's new low from the same pass.  The engine no longer
+  calls it: its parallel phase adds in place on the host block, since one
+  device round trip costs far more than the few thousand words it XORs.
+  ``gf2_find_low`` has no caller in the engine either; only tests reach
+  the two.
 
 The host-side rank-compression vocabulary lives here too: the sorted
 unique ``universe`` of active cofacet keys maps key ``universe[i]`` to bit

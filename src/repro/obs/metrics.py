@@ -73,11 +73,14 @@ SCHEMA: Dict[str, MetricSpec] = {s.name: s for s in [
           "high-water bytes of the packed bit block"),
     _spec("use_kernels", "gauge", "flag", "1 when Pallas kernels were used"),
     _spec("n_device_calls", "counter", "calls",
-          "gf2 kernel round trips (host array to device and back); "
-          "0 on the host path"),
+          "gf2 kernel round trips (host array to device and back): the "
+          "serial pre-pass; 0 on the host path"),
     _spec("n_kernel_lows", "counter", "rows",
-          "rows whose low came back with the gf2 call that wrote them; "
-          "0 on the host path"),
+          "rows whose low came back from the serial pre-pass that wrote "
+          "them; 0 on the host path"),
+    _spec("n_host_xor_rounds", "counter", "rounds",
+          "parallel-phase rounds whose packed rows took the in-place host "
+          "GF(2) add"),
     _spec("max_block_words", "gauge", "words",
           "widest packed block row (kernel path: as padded for the kernels)"),
     # -- distributed packed driver --

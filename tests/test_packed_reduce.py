@@ -19,6 +19,7 @@ from repro.core.packed_reduce import _PackedBatch, reduce_dimension_packed
 from repro.core.pairing import EMPTY_KEY
 from repro.core.reduction import (DimensionAdapter, PivotStore,
                                   merge_cancel, reduce_dimension)
+from repro.data import pointclouds as pc
 from repro.kernels.gf2 import (NO_LOW, bits_to_keys, find_low_np,
                                gf2_parallel_xor, gf2_serial_reduce,
                                pack_keys_to_bits, scatter_bits,
@@ -225,7 +226,13 @@ def test_packed_kernel_path_matches_host():
     assert np.array_equal(h2h.diagram(), h2k.diagram())
     for host_res, kern_res in ((host, kern), (h2h, h2k)):
         assert host_res.stats["n_kernel_lows"] == 0
-        assert kern_res.stats["n_kernel_lows"] > 0
+        # both paths share the parallel phase's in-place add; kernel lows
+        # come from the serial pre-pass alone
+        assert (kern_res.stats["n_host_xor_rounds"]
+                == host_res.stats["n_host_xor_rounds"] > 0)
+        assert ((kern_res.stats["n_kernel_lows"] > 0)
+                == (kern_res.stats["n_device_calls"] > 0))
+    assert h2k.stats["n_kernel_lows"] > 0
 
 
 def kernel_batch(seed, B=40, n_keys=300):
@@ -256,9 +263,9 @@ def unpacked_lows(blk):
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_kernel_path_lows_match_host_refresh(seed):
-    """Lows the gf2 calls return equal a host find-low of the same rows,
-    after the parallel XOR and after the serial pre-pass; evicted rows'
-    lows survive both."""
+    """Lows after the in-place parallel XOR, and those the serial kernel
+    returns, equal a host find-low of the same rows; evicted rows' lows
+    survive both."""
     rng, universe, blk = kernel_batch(seed)
     np.testing.assert_array_equal(blk.lows, unpacked_lows(blk))
     B = blk.B
@@ -267,13 +274,20 @@ def test_kernel_path_lows_match_host_refresh(seed):
     for i in hit:
         addends[i] = np.sort(rng.choice(universe, size=int(
             rng.integers(1, 30)), replace=False))
-    # one packed row cancels to zero
+    # one packed row's R part cancels to zero beside the identity bit the
+    # serial pre-pass keeps in its V-words: its low must read -1, not the
+    # V-word rank
     cancel = next(i for i in hit if i not in blk.scalar and blk.lows[i] >= 0)
     addends[cancel] = blk.unpack(np.array([cancel]))[0]
+    blk.block[cancel, blk.cap + (cancel >> 5)] |= \
+        np.uint32(1) << np.uint32(cancel & 31)
     n_packed = len([i for i in hit if i not in blk.scalar])
+    assert n_packed
     blk.xor_addends(hit, addends)
-    assert blk.n_kernel_lows == n_packed
-    assert blk.n_device_calls == 1
+    # the parallel add stays on the host: no gf2 trip, no kernel lows
+    assert blk.n_kernel_lows == 0
+    assert blk.n_device_calls == 0
+    assert blk.n_host_xor_rounds == 1
     assert blk.lows[cancel] == -1
     np.testing.assert_array_equal(blk.lows, unpacked_lows(blk))
 
@@ -288,13 +302,97 @@ def test_kernel_path_lows_match_host_refresh(seed):
     changed = {}
     n_red = blk._serial_kernel_prepass(gens, list(range(B)), changed)
     assert n_red > 0 and changed
-    assert blk.n_kernel_lows == n_packed + len(changed)
-    assert blk.n_device_calls == 2
+    assert blk.n_kernel_lows == len(changed)
+    assert blk.n_device_calls == 1
     assert dup in changed and blk.lows[dup] == -1
     np.testing.assert_array_equal(blk.lows, unpacked_lows(blk))
     assert {c: int(blk.lows[c]) for c in blk.scalar} == scalar_lows
     live = [i for i in range(B) if blk.lows[i] >= 0 and i not in blk.scalar]
     assert len({int(blk.lows[i]) for i in live}) == len(live)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_refresh_lows_give_the_kernel_lows_of_a_whole_row(seed):
+    """On the kernel path's one segment, the host find-low gives exactly
+    the keys ``_set_kernel_lows`` makes of a whole-row first set bit: V-word
+    bits and slack bits past the universe read as empty."""
+    rng, universe, blk = kernel_batch(seed)
+    B, cap, n = blk.B, blk.cap, len(blk.segs[0])
+    assert blk.r_words * 32 > n          # the segment has slack bits
+    packed = [i for i in range(B) if i not in blk.scalar]
+    emptied = packed[:4]
+    blk.block[emptied, :blk.r_words] = 0
+    for k, i in enumerate(packed):
+        # the identity bit the serial pre-pass plants in the V-words
+        blk.block[i, cap + (i >> 5)] |= np.uint32(1) << np.uint32(i & 31)
+        if k % 3 == 0:                   # a stray bit past the universe
+            p = int(rng.integers(n, blk.r_words * 32))
+            blk.block[i, p >> 5] |= np.uint32(1) << np.uint32(p & 31)
+    rows = np.asarray(packed, dtype=np.int64)
+    blk.refresh_lows(rows)
+    host = blk.lows[rows].copy()
+    blk._set_kernel_lows(packed, find_low_np(blk.block[rows]))
+    np.testing.assert_array_equal(host, blk.lows[rows])
+    np.testing.assert_array_equal(host, unpacked_lows(blk)[rows])
+    assert (host[:len(emptied)] == -1).all() and (host >= 0).any()
+
+
+def banded_contacts(n=48, width=10, seed=3):
+    """A small balanced contact map as COO distances: contacts decay with
+    the bin distance, three loop pixels are boosted."""
+    rng = np.random.default_rng(seed)
+    i, s = np.meshgrid(np.arange(n), np.arange(1, width + 1), indexing="ij")
+    i, s = i.ravel(), s.ravel()
+    keep = i + s < n
+    i, j, s = i[keep], (i + s)[keep], s[keep]
+    lam = 30.0 / s
+    lam[rng.choice(i.size, size=3, replace=False)] *= 4.0
+    c = rng.poisson(lam).astype(np.float64)
+    hit = c > 0
+    return i[hit], j[hit], 1.0 / c[hit], n
+
+
+KERNEL_PATH_CALLS = {
+    "o3": lambda: dict(points=pc.o3_points(20, seed=4), tau_max=np.inf,
+                       maxdim=2),
+    "coo": lambda: dict(coo=banded_contacts(), tau_max=0.2, maxdim=1),
+}
+
+
+@pytest.mark.parametrize("mode", ["explicit", "implicit"])
+@pytest.mark.parametrize("name", sorted(KERNEL_PATH_CALLS))
+def test_kernel_path_adds_on_the_host_and_matches_single(monkeypatch, name,
+                                                         mode):
+    """With the gf2 kernels on (interpreted), the packed engine's diagrams
+    equal ``reduce_dimension``'s bit for bit, the parallel add never calls
+    ``gf2_parallel_xor``, and every gf2 round trip is a serial pre-pass."""
+    from repro.kernels import gf2
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("gf2_parallel_xor called")
+
+    serial_calls = []
+
+    def serial(*args, _fn=gf2.gf2_serial_reduce, **kwargs):
+        serial_calls.append(1)
+        return _fn(*args, **kwargs)
+
+    monkeypatch.setattr(gf2, "gf2_parallel_xor", refuse)
+    monkeypatch.setattr(gf2, "gf2_serial_reduce", serial)
+    monkeypatch.setattr("repro.core.packed_reduce._resolve_use_kernels",
+                        lambda use_kernels: True)
+    kw = KERNEL_PATH_CALLS[name]()
+    dims = list(range(kw["maxdim"] + 1))
+    ref = compute_ph(mode=mode, engine="single", **kw)
+    got = compute_ph(mode=mode, engine="packed", batch_size=32, **kw)
+    assert_diagrams_equal(got.diagrams, ref.diagrams, dims=dims, atol=0.0)
+    st = got.stats
+    pre = [f"h{d}_" for d in dims[1:]]
+    assert all(st[p + "use_kernels"] == 1 for p in pre)
+    assert sum(st[p + "n_host_xor_rounds"] for p in pre) > 0
+    assert serial_calls
+    assert sum(st[p + "n_device_calls"] for p in pre) == len(serial_calls)
+    assert sum(st[p + "n_kernel_lows"] for p in pre) > 0
 
 
 def test_packed_h2_full_pipeline_vs_oracle():
@@ -364,8 +462,6 @@ def test_packed_beats_single_reductions_per_sec():
     """The point of the engine: more reductions/sec than the single-column
     engine on a reduction-heavy workload (the benchmark asserts >= 5x in
     CI; in-suite we only require a win to stay robust to runner noise)."""
-    from repro.data import pointclouds as pc
-
     dists = pc.fractal_like(40, seed=0)
     rps = {}
     for engine in ("single", "packed"):
