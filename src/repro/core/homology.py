@@ -278,6 +278,11 @@ def compute_ph(
                         tile_m=tile_m, tile_n=tile_n, return_stats=True)
                 reg.gauge("harvest_pallas").set(
                     float(tile_stats.backend == "pallas"))
+                reg.counter("harvest_tiles").inc(tile_stats.tiles_visited)
+                reg.counter("harvest_fetch_bytes").inc(
+                    tile_stats.fetch_bytes)
+                reg.counter("harvest_candidates").inc(
+                    tile_stats.candidate_pairs)
             elif backend == "dense":
                 filt = build_filtration(points=points, dists=dists,
                                         tau_max=tau_max)

@@ -134,6 +134,15 @@ SCHEMA: Dict[str, MetricSpec] = {s.name: s for s in [
           "COO input: pairs kept as edges (finite, at most tau_max)"),
     _spec("harvest_pallas", "gauge", "flag",
           "tiled harvest backend: 1 = Pallas distance kernel, 0 = numpy"),
+    _spec("harvest_tiles", "counter", "tiles",
+          "tiled harvest: tiles visited"),
+    _spec("harvest_fetch_bytes", "counter", "bytes",
+          "tiled harvest: device tiles copied to the host, each at its "
+          "cropped extent (4 bytes an f32 distance, 1 a candidate-mask "
+          "entry of a sharded dists round); 0 on the numpy backend"),
+    _spec("harvest_candidates", "counter", "pairs",
+          "tiled harvest: f32 candidate pairs re-measured exactly; 0 on "
+          "the numpy backend"),
     _spec("per_device_peak_bytes", "gauge", "bytes",
           "sharded harvest: predicted per-device high-water"),
     _spec("per_device_base_bytes", "gauge", "bytes",

@@ -212,6 +212,7 @@ def _harvest_shards_device(points, sq, shards, tau_max, tile_m, tile_n,
         for k, si, ei, sj, ej in live:
             if stats is not None:
                 stats.tiles_visited += 1
+                stats.fetch_bytes += (ei - si) * (ej - sj) * d2.itemsize
             with span("harvest/refine", lane=k, round=r, tile=f"{si},{sj}"):
                 # crop to the real extent first: zero-padded rows fabricate
                 # origin distances that must never reach the threshold test
@@ -270,6 +271,7 @@ def _harvest_shards_device_dists(dists, shards, tau_max, tile_m, tile_n,
         for k, si, ei, sj, ej in live:
             if stats is not None:
                 stats.tiles_visited += 1
+                stats.fetch_bytes += (ei - si) * (ej - sj) * cand.itemsize
             with span("harvest/refine", lane=k, round=r, tile=f"{si},{sj}"):
                 # crop to the real extent first: the inf padding is masked
                 # out by construction, the crop keeps the index math honest

@@ -57,6 +57,7 @@ class TileStats:
     backend: str = "numpy"
     tiles_visited: int = 0
     candidate_pairs: int = 0      # pallas path: f32 candidates refined in f64
+    fetch_bytes: int = 0          # device tiles copied to the host, cropped
     peak_tile_bytes: int = 0      # largest per-tile scratch (per device)
     harvest_bytes: int = 0        # final sorted COO triplet arrays
     merge_peak_bytes: int = 0     # worst transient during concat + lexsort
@@ -178,21 +179,23 @@ def _refine_f32_tile(d2_32: np.ndarray, points: np.ndarray, sq: np.ndarray,
     the output is bit-identical to the numpy tile regardless of which device
     (or how many devices) produced ``d2_32``.
     """
-    upper = _upper_mask(si, ei, sj, ej)
-    cand = d2_32 <= thr32
-    if upper is not None:
-        cand &= upper
-    if stats is not None:
-        stats.peak_tile_bytes = max(
-            stats.peak_tile_bytes, d2_32.nbytes + cand.nbytes
-            + (0 if upper is None else upper.nbytes))
-    ri, rj = np.nonzero(cand)
-    iu, ju = si + ri, sj + rj
-    lens = np.sqrt(pair_sq_dists(points, iu, ju, sq))
-    if stats is not None:
-        stats.candidate_pairs += int(iu.size)
-    keep = lens <= tau_max
-    return iu[keep], ju[keep], lens[keep]
+    with span("harvest/threshold"):
+        upper = _upper_mask(si, ei, sj, ej)
+        cand = d2_32 <= thr32
+        if upper is not None:
+            cand &= upper
+        if stats is not None:
+            stats.peak_tile_bytes = max(
+                stats.peak_tile_bytes, d2_32.nbytes + cand.nbytes
+                + (0 if upper is None else upper.nbytes))
+        ri, rj = np.nonzero(cand)
+    with span("harvest/remeasure"):
+        iu, ju = si + ri, sj + rj
+        lens = np.sqrt(pair_sq_dists(points, iu, ju, sq))
+        if stats is not None:
+            stats.candidate_pairs += int(iu.size)
+        keep = lens <= tau_max
+        return iu[keep], ju[keep], lens[keep]
 
 
 def _f32_threshold(points: np.ndarray, sq: np.ndarray,
@@ -227,20 +230,22 @@ def _refine_f32_dists_tile(cand: np.ndarray, dists: np.ndarray,
     f64 matrix, so the output is bit-identical to the host dists tile for
     any device count.
     """
-    upper = _upper_mask(si, ei, sj, ej)
-    if upper is not None:
-        cand = cand & upper
-    if stats is not None:
-        stats.peak_tile_bytes = max(
-            stats.peak_tile_bytes,
-            2 * cand.nbytes + (0 if upper is None else upper.nbytes))
-    ri, rj = np.nonzero(cand)
-    iu, ju = si + ri, sj + rj
-    lens = np.asarray(dists[iu, ju], dtype=np.float64)
-    if stats is not None:
-        stats.candidate_pairs += int(iu.size)
-    keep = lens <= tau_max
-    return iu[keep], ju[keep], lens[keep]
+    with span("harvest/threshold"):
+        upper = _upper_mask(si, ei, sj, ej)
+        if upper is not None:
+            cand = cand & upper
+        if stats is not None:
+            stats.peak_tile_bytes = max(
+                stats.peak_tile_bytes,
+                2 * cand.nbytes + (0 if upper is None else upper.nbytes))
+        ri, rj = np.nonzero(cand)
+    with span("harvest/remeasure"):
+        iu, ju = si + ri, sj + rj
+        lens = np.asarray(dists[iu, ju], dtype=np.float64)
+        if stats is not None:
+            stats.candidate_pairs += int(iu.size)
+        keep = lens <= tau_max
+        return iu[keep], ju[keep], lens[keep]
 
 
 def iter_tile_edges(
@@ -321,6 +326,8 @@ def iter_tile_edges(
                         # analyze: allow[host-sync] one gather per tile is the streaming contract; the f64 refine consumes it on host
                         d2_32 = np.asarray(pairwise_sq_dists(
                             pts32[si:ei], pts32[sj:ej], interpret=interpret))
+                    if stats is not None:
+                        stats.fetch_bytes += d2_32.nbytes
                     with span("harvest/refine"):
                         return _refine_f32_tile(d2_32, points, sq, si, ei,
                                                 sj, ej, tau_max, thr32, stats)

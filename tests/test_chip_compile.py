@@ -64,6 +64,16 @@ def test_pairwise_sq_dists_compiles(one_chip, d):
     assert "tpu_custom_call" in text
 
 
+# torus4 at n = 50,000 in tiles of 2,048 leaves a last tile row and column
+# 848 wide: the wrapper pads those to its 256 blocks and crops the result
+@pytest.mark.parametrize("m,n", [(2048, 848), (848, 2048), (848, 848)])
+def test_pairwise_sq_dists_compiles_ragged(one_chip, m, n):
+    text = compiled_text(
+        lambda x, y: pairwise_sq_dists(x, y, interpret=False), one_chip,
+        ((m, 4), jnp.float32), ((n, 4), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("w", [PHASE_A_WORDS, SMOKE_WORDS])
 def test_gf2_find_low_compiles(one_chip, w):
     text = compiled_text(lambda c: gf2_find_low(c, interpret=False),
