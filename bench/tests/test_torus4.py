@@ -50,6 +50,9 @@ def test_configuration_is_the_published_set():
     assert cell.mix == {"loop": "closed", "tau_max": 0.025, "maxdim": 1}
     entry = {c["name"]: c for c in bench["configs"]}["torus4_50k"]
     assert entry["reduced"] == cell.config["reduced"] == ["tau_max"]
+    assert "torus4" in entry["source"]
+    others = [c["source"] for c in bench["configs"] if c["name"] != "torus4_50k"]
+    assert entry["source"] not in others
     assert {"harvest_fetch_mb", "harvest_threshold_s", "harvest_s",
             "reduce_s", "dist_roofline"} <= set(cell.readers)
     assert "coo_build_s" not in cell.readers
